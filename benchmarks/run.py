@@ -414,7 +414,9 @@ BENCHES = {
 
 def main() -> None:
     from benchmarks.common import write_json
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None, choices=list(BENCHES))

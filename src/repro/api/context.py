@@ -44,20 +44,25 @@ from repro.core.topology import metropolis
 @jax.tree_util.register_pytree_node_class
 class SimContext:
     """Immutable bundle of (cfg, task, q, adj, w_sym, data, positions,
-    flat_spec, schedule, overrides, tape).
+    flat_spec, schedule, overrides, tape, use_kernel).
 
     `task` is the workload: a `repro.tasks.Task` or — the legacy shim —
     a bare loss callable (plain SGD). `overrides` is a
     `repro.core.protocol.Overrides` of traced config re-bindings
     (lr/lambda/psi), set per grid row by the sweep engine; None (the
     default everywhere else) is the plain static-config path.
+    `use_kernel` is the gossip drain's lowering: None chooses by backend
+    (`gossip_ops.default_use_kernel`); the sweep engine sets False on a
+    multi-device mesh, where XLA partitions the drain and could not
+    partition the Pallas kernel.
     """
 
     __slots__ = ("cfg", "task", "q", "adj", "w_sym", "data", "positions",
-                 "flat_spec", "schedule", "overrides", "tape")
+                 "flat_spec", "schedule", "overrides", "tape", "use_kernel")
 
     def __init__(self, cfg, task, q, adj, w_sym, data, positions=None,
-                 flat_spec=None, schedule=None, overrides=None, tape=None):
+                 flat_spec=None, schedule=None, overrides=None, tape=None,
+                 use_kernel=None):
         object.__setattr__(self, "cfg", cfg)
         object.__setattr__(self, "task", task)
         object.__setattr__(self, "q", q)
@@ -69,6 +74,7 @@ class SimContext:
         object.__setattr__(self, "schedule", schedule)
         object.__setattr__(self, "overrides", overrides)
         object.__setattr__(self, "tape", tape)
+        object.__setattr__(self, "use_kernel", use_kernel)
 
     def __setattr__(self, name, value):
         raise AttributeError("SimContext is immutable")
@@ -89,15 +95,15 @@ class SimContext:
     def tree_flatten(self):
         children = (self.q, self.adj, self.w_sym, self.data, self.positions,
                     self.schedule, self.overrides, self.tape)
-        aux = (self.cfg, self.task, self.flat_spec)
+        aux = (self.cfg, self.task, self.flat_spec, self.use_kernel)
         return children, aux
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        cfg, task, flat_spec = aux
+        cfg, task, flat_spec, use_kernel = aux
         q, adj, w_sym, data, positions, schedule, overrides, tape = children
         return cls(cfg, task, q, adj, w_sym, data, positions, flat_spec,
-                   schedule, overrides, tape)
+                   schedule, overrides, tape, use_kernel)
 
     def __repr__(self):
         n = self.q.shape[0] if self.q is not None else "?"

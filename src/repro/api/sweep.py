@@ -318,7 +318,10 @@ def simulate_sweep(
     if mesh is not None:
         states, shard_data = shard_grid_inputs(states, ctx.data,
                                                base.num_clients, mesh)
-        ctx = ctx.replace(data=shard_data)
+        # the partitioner splits the XLA drain; it cannot split a Mosaic
+        # kernel
+        ctx = ctx.replace(data=shard_data,
+                          use_kernel=False if mesh.size > 1 else None)
 
     finals, raw = _run_sweep(algo, ctx, states, eval_data, int(num_steps),
                              int(eval_every), eval_fn, overrides, sched_stack,

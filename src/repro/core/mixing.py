@@ -25,17 +25,6 @@ from jax.sharding import PartitionSpec as P
 from repro.kernels.gossip import ops as gossip_ops
 
 
-def _resolve_shard_map():
-    """Version-tolerant shard_map lookup: top-level `jax.shard_map` on
-    recent JAX, `jax.experimental.shard_map.shard_map` on older releases."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    from jax.experimental.shard_map import shard_map as sm
-
-    return sm
-
-
 def receive_counts(q_mask) -> jax.Array:
     """Messages incoming per receiver j: count of nonzero column entries."""
     return (q_mask > 0).sum(axis=0)
@@ -81,7 +70,8 @@ def mix_dense(q_eff, deltas, *, use_kernel=None, interpret=None,
     if use_kernel:
         out = gossip_ops.gossip_mix(q_eff, flat, interpret=interpret)
     else:
-        out = jnp.einsum("nm,nk->mk", q_eff.astype(compute_dtype), flat)
+        out = jnp.einsum("nm,nk->mk", q_eff.astype(compute_dtype), flat,
+                         precision=jax.lax.Precision.HIGHEST)
     return flat_lib.unravel_clients(out, spec)
 
 
@@ -109,8 +99,6 @@ def mix_ring_shardmap(mesh, client_axes, deltas, w_fwd: float = 0.5, w_bwd: floa
     P(clients, None, ...) spec forces an all-gather of expert/TP-sharded
     leaves over "model" before the permute — measured regression).
     """
-    shard_map = _resolve_shard_map()
-
     from repro.sharding.specs import param_spec
 
     axes = client_axes if isinstance(client_axes, tuple) else (client_axes,)
@@ -147,7 +135,7 @@ def mix_ring_shardmap(mesh, client_axes, deltas, w_fwd: float = 0.5, w_bwd: floa
 
         return jax.tree_util.tree_map(lambda x: leaf(x, gf, gb), d)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(in_specs, gspec, gspec),
         out_specs=in_specs,

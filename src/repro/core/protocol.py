@@ -379,9 +379,25 @@ def _unify(params, accept_count, widx, cfg, n):
     return jax.lax.cond(do_unify, unify, lambda a: a, (params, accept_count))
 
 
+def drain_weights(state: DracoState, D: int):
+    """`(ages, slots, w_stack)` of the drain at `state.window_idx`.
+
+    The stored broadcast of age j (sent in window widx-j) arrives now iff
+    its per-link delay equals j. Stacked oldest-first, so the f32
+    accumulation order matches the seed ring buffer exactly; the drain's
+    `(J, N, N)` weights against ring rows `slots` (J = D-1)."""
+    ages = jnp.arange(D - 1, 0, -1, dtype=jnp.int32)
+    slots = jnp.mod(state.window_idx - ages, D)
+    w_stack = state.w_ring[slots] * (
+        state.delay_ring[slots] == ages[:, None, None]
+    ).astype(state.w_ring.dtype)
+    return ages, slots, w_stack
+
+
 def draco_window(state: DracoState, cfg: DracoConfig, q, adj, task, data,
                  spec=None, *, positions=None, compute_rate=None,
-                 tx_rate=None, overrides=None, damping=None):
+                 tx_rate=None, overrides=None, damping=None,
+                 use_kernel=None):
     """One superposition window on the fused gossip engine.
 
     Bit-for-bit equal to `draco_window_legacy` at f32 (the parity suite
@@ -414,6 +430,10 @@ def draco_window(state: DracoState, cfg: DracoConfig, q, adj, task, data,
     the staleness-adaptive mixing hook (`repro.events.staleness`
     builds the FedAsync constant/hinge/poly vectors). None keeps the
     undamped drain bit-for-bit.
+
+    `use_kernel` picks the drain's lowering (`gossip_ops.gossip_drain`):
+    None chooses by backend, False forces XLA's, which a program
+    partitioned over several devices needs.
     """
     n, D = cfg.num_clients, cfg.max_delay_windows
     ov = overrides or Overrides()
@@ -424,17 +444,11 @@ def draco_window(state: DracoState, cfg: DracoConfig, q, adj, task, data,
         spec = flat_lib.spec_of(state.params)
 
     # --- 1. deliveries: fused delay-bucketed drain on the flat plane ------
-    # Stored broadcast of age j (sent in window widx-j) arrives now iff its
-    # per-link delay equals j.  Stack oldest-first so the f32 accumulation
-    # order matches the seed ring buffer exactly.
-    ages = jnp.arange(D - 1, 0, -1, dtype=jnp.int32)
-    slots = jnp.mod(widx - ages, D)
-    w_stack = state.w_ring[slots] * (
-        state.delay_ring[slots] == ages[:, None, None]
-    ).astype(state.w_ring.dtype)
+    ages, slots, w_stack = drain_weights(state, D)
     if damping is not None:
         w_stack = w_stack * damping[ages][:, None, None]
-    arrivals_flat = gossip_ops.gossip_drain(w_stack, state.buffer, slots)
+    arrivals_flat = gossip_ops.gossip_drain(w_stack, state.buffer, slots,
+                                            use_kernel=use_kernel)
     arrivals = flat_lib.unravel_clients(arrivals_flat, spec)
     params = jax.tree_util.tree_map(
         lambda p, a: p + a.astype(p.dtype), state.params, arrivals

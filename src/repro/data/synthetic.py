@@ -13,7 +13,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import cross_entropy, dense_init
+from repro.models.layers import cross_entropy, dense_init, top1_accuracy
 
 
 def classification_task(key, n_samples: int, input_dim: int, num_classes: int,
@@ -93,6 +93,6 @@ def make_mlp(key, input_dim: int, hidden: tuple, num_classes: int):
         return cross_entropy(apply(p, x), y)
 
     def accuracy(p, x, y):
-        return (apply(p, x).argmax(-1) == y).mean()
+        return top1_accuracy(apply(p, x), y)
 
     return params, apply, loss, accuracy

@@ -104,6 +104,6 @@ class FedAsyncWindow(Draco):
             state, ctx.cfg, v.q, v.adj, ctx.task, ctx.data,
             spec=ctx.flat_spec, positions=v.positions,
             compute_rate=v.compute_rate, tx_rate=v.tx_rate,
-            overrides=ctx.overrides,
+            overrides=ctx.overrides, use_kernel=ctx.use_kernel,
             damping=staleness_damping_vector(ctx.cfg),
         )

@@ -153,7 +153,8 @@ def event_step(state: EventState, ctx, *, damping=None,
     if damping is not None:
         dtau = (t - state.send_time[slots]) / cfg.window
         w_stack = w_stack * damping(dtau)[:, None, None]
-    arrivals_flat = gossip_ops.gossip_drain(w_stack, state.buffer, slots)
+    arrivals_flat = gossip_ops.gossip_drain(w_stack, state.buffer, slots,
+                                            use_kernel=ctx.use_kernel)
     arrivals = flat_lib.unravel_clients(arrivals_flat, spec)
     params = jax.tree_util.tree_map(
         lambda p, a: p + a.astype(p.dtype), state.params, arrivals

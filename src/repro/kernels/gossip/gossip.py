@@ -10,9 +10,13 @@ TPU-native blocking rationale:
     HBM->VMEM, multiplies by the resident (N, N) Q tile on the MXU and
     writes one (N, block_d) output tile. Every delta byte moves exactly
     once — the kernel is purely memory-bound, matching its roofline role.
-  - N (the client-axis, 16..64) is zero-padded to the 8-sublane multiple
-    by the wrapper in ops.py; accumulation is f32 regardless of input
-    dtype (bf16 deltas are common).
+  - Each block spans all N rows (the client axis, 16..64), so N needs
+    no 8-sublane padding; accumulation is f32 regardless of input dtype
+    (bf16 deltas are common).
+  - Every dot runs at ``precision=HIGHEST``: the protocol mixes in f32,
+    and the MXU's default single bf16 pass would round the f32 weights
+    and payloads to 8 mantissa bits. The kernels are memory-bound, so
+    the extra passes cost no time.
 """
 from __future__ import annotations
 
@@ -21,21 +25,27 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _gossip_kernel(q_ref, d_ref, o_ref):
     q = q_ref[...].astype(jnp.float32)  # (N, N) resident
     d = d_ref[...].astype(jnp.float32)  # (N, block_d)
     o_ref[...] = jnp.dot(
-        q.T, d, preferred_element_type=jnp.float32
+        q.T, d, precision=_HIGHEST, preferred_element_type=jnp.float32
     ).astype(o_ref.dtype)
 
 
 def gossip_mix_pallas(q, deltas, *, block_d: int = 512, interpret: bool = False):
-    """q (N, N) f32; deltas (N, K) with K % block_d == 0 (padded by ops)."""
+    """q (N, N) f32; deltas (N, K), unpadded.
+
+    Each block spans all N rows (a block dim equal to the array dim needs
+    no 8-sublane multiple), so no padded copy of the (N, K) plane is made.
+    A ragged last K tile is fine: output columns depend only on their own
+    input columns, and its out-of-bounds lanes are never written."""
     n, d_total = deltas.shape
     assert q.shape == (n, n)
-    assert d_total % block_d == 0, (d_total, block_d)
-    grid = (d_total // block_d,)
+    grid = (pl.cdiv(d_total, block_d),)
     return pl.pallas_call(
         _gossip_kernel,
         grid=grid,
@@ -54,9 +64,8 @@ def _enqueue_kernel(w_ref, p_ref, o_ref):
     p = p_ref[...].astype(jnp.float32)  # read the tile from HBM exactly once
     for j in range(w_ref.shape[0]):  # static unroll: J small (D-1)
         w = w_ref[j].astype(jnp.float32)
-        o_ref[j] = jnp.dot(w.T, p, preferred_element_type=jnp.float32).astype(
-            o_ref.dtype
-        )
+        o_ref[j] = jnp.dot(w.T, p, precision=_HIGHEST,
+                           preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
 def gossip_enqueue_pallas(w_stack, pending, *, block_d: int = 512,
@@ -93,7 +102,8 @@ def _drain_kernel(w_ref, p_ref, o_ref):
     for j in range(w_ref.shape[0]):  # static unroll; order = stack order
         w = w_ref[j].astype(jnp.float32)
         p = p_ref[j].astype(jnp.float32)  # each payload tile read once
-        acc = acc + jnp.dot(w.T, p, preferred_element_type=jnp.float32)
+        acc = acc + jnp.dot(w.T, p, precision=_HIGHEST,
+                            preferred_element_type=jnp.float32)
     o_ref[...] = acc.astype(o_ref.dtype)
 
 

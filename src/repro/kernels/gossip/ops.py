@@ -2,12 +2,16 @@
 
 Backend auto-selection (one policy for every wrapper):
 
-  - ``use_kernel=None``  -> Pallas only on TPU; pure-XLA lowering elsewhere
-    (the kernel path in ``interpret`` mode is a correctness tool, far too
-    slow for CPU CI hot loops).
+  - ``use_kernel=None``  -> Pallas only on TPU; pure-XLA lowering
+    elsewhere (the kernel path in ``interpret`` mode is a correctness
+    tool, far too slow for CPU CI hot loops). Mosaic kernels cannot be
+    partitioned automatically, so a caller that jits over a multi-device
+    mesh without a ``shard_map`` passes ``use_kernel=False``.
   - ``interpret=None``   -> interpret mode exactly when not on TPU, so
     explicitly requesting the kernel path off-TPU still works (tests),
     while on TPU the compiled kernel is actually exercised.
+
+Both lowerings multiply at ``precision=HIGHEST`` (f32 mixing on TPU).
 """
 from __future__ import annotations
 
@@ -46,15 +50,13 @@ def _pad_to(x, mult, axis):
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def gossip_mix(q, deltas, *, block_d: int = 512, interpret=None):
-    """out = Q^T @ deltas with TPU-friendly padding; q (N, N) and
-    deltas (N, K) flat updates -> (N, K)."""
+    """out = Q^T @ deltas; q (N, N) and deltas (N, K) flat updates ->
+    (N, K). No padding: at model width the (N, K) plane is most of the
+    device's memory, and a padded copy would double it."""
     if interpret is None:
         interpret = default_interpret()
-    n, d = deltas.shape
-    qp = _pad_to(_pad_to(q.astype(jnp.float32), 8, 0), 8, 1)
-    dp = _pad_to(_pad_to(deltas, 8, 0), block_d, 1)
-    out = gossip_mix_pallas(qp, dp, block_d=block_d, interpret=interpret)
-    return out[:n, :d]
+    return gossip_mix_pallas(q.astype(jnp.float32), deltas, block_d=block_d,
+                             interpret=interpret)
 
 
 def gossip_mix_reference(q, deltas):
@@ -135,7 +137,8 @@ def gossip_drain(w_stack, ring, slots, *, block_d: int = 512, use_kernel=None,
 
         def _acc(o, w_j=w_j, j=j):
             p = jax.lax.dynamic_index_in_dim(ring, slots[j], 0, keepdims=False)
-            return o + jax.lax.dot(w_j.T, p.astype(jnp.float32))
+            return o + jax.lax.dot(w_j.T, p.astype(jnp.float32),
+                                   precision=jax.lax.Precision.HIGHEST)
 
         out = jax.lax.cond(jnp.any(w_j != 0), _acc, lambda o: o, out)
     return out
@@ -152,7 +155,8 @@ def gossip_drain_sharded(w_stack, ring, slots, mesh, client_axes, *,
     the Pallas grid on TPU, the unrolled-GEMM fallback elsewhere — and a
     single ``lax.psum_scatter`` over the *receiver* axis both sums the
     per-device partials and leaves each device holding exactly its own
-    clients' aggregate (no all-reduce, no gather).
+    clients' aggregate (no gather; the TPU compiler may lower it as an
+    all-reduce plus a local slice, as it does at paper scale on v5e).
 
     w_stack (J, N, N) and ring (S, N, K) are both sharded on their
     *sender* axis (axis 1) over `client_axes` (a mesh axis name or
@@ -167,9 +171,6 @@ def gossip_drain_sharded(w_stack, ring, slots, mesh, client_axes, *,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.mixing import _resolve_shard_map
-
-    shard_map = _resolve_shard_map()
     axes = client_axes if isinstance(client_axes, tuple) else (client_axes,)
     # one name for both roles: PartitionSpec entry and collective axis
     ax = axes if len(axes) > 1 else axes[0]
@@ -190,10 +191,10 @@ def gossip_drain_sharded(w_stack, ring, slots, mesh, client_axes, *,
         return jax.lax.psum_scatter(partial_full, ax,
                                     scatter_dimension=0, tiled=True)
 
-    # check_rep=False: pallas_call has no shard_map replication rule (the
-    # kernel path would otherwise raise NotImplementedError); the output
-    # spec is exact — psum_scatter leaves each device its receiver rows
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(None, ax, None), P(None, ax, None), P()),
-                   out_specs=P(ax, None), check_rep=False)
+    # check_vma=False: pallas_call has no shard_map varying-axes rule;
+    # the output spec is exact — psum_scatter leaves each device its
+    # receiver rows
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(None, ax, None), P(None, ax, None), P()),
+                       out_specs=P(ax, None), check_vma=False)
     return fn(w_stack, ring, slots)

@@ -1,5 +1,8 @@
-"""Pure-jnp oracles for the gossip kernels."""
+"""Pure-jnp oracles for the gossip kernels (f32 matmuls at HIGHEST)."""
+import jax
 import jax.numpy as jnp
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def gossip_mix_ref(q, deltas):
@@ -9,8 +12,8 @@ def gossip_mix_ref(q, deltas):
     Accumulation in f32, output in deltas.dtype.
     """
     out = jnp.einsum(
-        "nm,nd->md", q.astype(jnp.float32), deltas.astype(jnp.float32)
-    )
+        "nm,nd->md", q.astype(jnp.float32), deltas.astype(jnp.float32),
+        precision=_HIGHEST)
     return out.astype(deltas.dtype)
 
 
@@ -21,8 +24,8 @@ def gossip_enqueue_ref(w_stack, pending, out_dtype=None):
     (N, K).  f32 accumulation; output dtype defaults to pending.dtype.
     """
     out = jnp.einsum(
-        "jnm,nk->jmk", w_stack.astype(jnp.float32), pending.astype(jnp.float32)
-    )
+        "jnm,nk->jmk", w_stack.astype(jnp.float32), pending.astype(jnp.float32),
+        precision=_HIGHEST)
     return out.astype(pending.dtype if out_dtype is None else out_dtype)
 
 
@@ -33,6 +36,6 @@ def gossip_drain_ref(w_stack, payloads, out_dtype=jnp.float32):
     f32 accumulation.
     """
     out = jnp.einsum(
-        "jnm,jnk->mk", w_stack.astype(jnp.float32), payloads.astype(jnp.float32)
-    )
+        "jnm,jnk->mk", w_stack.astype(jnp.float32), payloads.astype(jnp.float32),
+        precision=_HIGHEST)
     return out.astype(out_dtype)

@@ -39,7 +39,10 @@ def _ssd_chunk_kernel(c_ref, b_ref, x_ref, cums_ref, dt_ref, y_ref, s_ref):
     scores = CB * L * dt[None, :]
     y_ref[0, 0] = jnp.dot(scores, X, preferred_element_type=jnp.float32).astype(y_ref.dtype)
 
-    decay_dt = jnp.exp(cums[-1] - cums) * dt  # (Q,)
+    # last row through the ref: `cums[-1]` lowers to a dynamic_slice,
+    # which the TPU lowering does not implement
+    last = cums_ref[0, 0, Q - 1].astype(jnp.float32)  # (1,)
+    decay_dt = jnp.exp(last - cums) * dt  # (Q,)
     Bw = B * decay_dt[:, None]
     s_ref[0, 0] = jnp.dot(Bw.T, X, preferred_element_type=jnp.float32).astype(s_ref.dtype)
 

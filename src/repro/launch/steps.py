@@ -229,6 +229,9 @@ def make_train_step(cfg: ModelConfig, mesh, *, lr: float = 1e-3,
     rules = train_rules(mesh, seq_parallel=seq_parallel)
     bat = 10**9 if cost_variant else blocked_threshold
     spmd_axis = caxes if len(caxes) > 1 else caxes[0]
+    # the partitioner splits the einsum mix; it cannot split the Pallas
+    # kernel, which runs only where the mesh is one device
+    use_kernel = None if mesh.size == 1 else False
 
     def train_step(params, batch, q_eff):
         def client_loss(p_i, b_i):
@@ -242,7 +245,8 @@ def make_train_step(cfg: ModelConfig, mesh, *, lr: float = 1e-3,
             delta = jax.tree_util.tree_map(lambda g: (-lr * g).astype(g.dtype), grads)
             if mix_mode == "dense":
                 md = mix_dtype or jnp.float32
-                add = mixing.mix_dense(q_eff, delta, compute_dtype=md)
+                add = mixing.mix_dense(q_eff, delta, compute_dtype=md,
+                                       use_kernel=use_kernel)
                 new_params = jax.tree_util.tree_map(
                     lambda p, a: p + a.astype(p.dtype), params, add
                 )

@@ -10,9 +10,15 @@ weights) goes through `repro.api.make_context`, the same context the
 simulation driver uses, so the trainer and the paper-figure benchmarks
 share one graph/channel setup path.
 
+The mesh is built from the visible devices: (data=clients,
+model=rest) when every client gets its own device group, else one
+device holds every client replica.
+
 Examples:
   PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b --reduced \
-      --steps 200 --clients 4 --mesh 2x2
+      --steps 200 --clients 4
+  PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b --depth 2 \
+      --clients 2 --steps 10    # published width, 2 layers, one chip
 """
 from __future__ import annotations
 
@@ -25,11 +31,13 @@ import numpy as np
 
 from repro import checkpoint as ckpt_lib
 from repro.api import make_context
-from repro.configs.base import get_config, get_reduced
+from repro.configs.base import ShapeConfig, get_config, get_reduced
 from repro.core import mixing
 from repro.core.events import sample_event_masks
 from repro.core.protocol import DracoConfig
+from repro.launch import mesh as mesh_lib
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 
 
@@ -64,10 +72,24 @@ def select_batch(data, idx, batch_per_client: int):
             for k, v in data.items()}
 
 
-def main(argv=None):
+def client_mesh(n_clients: int, devices=None):
+    """(data=n, model=rest) mesh over `devices` (default: all) when every
+    client gets its own device group; else a (1, 1) mesh on the first
+    device, which then holds all n client replicas."""
+    devices = jax.devices() if devices is None else list(devices)
+    model_par = len(devices) // n_clients
+    shape = (n_clients, model_par) if model_par else (1, 1)
+    return mesh_lib.make_mesh(shape, ("data", "model"),
+                              devices=devices[:shape[0] * shape[1]])
+
+
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b")
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--depth", type=int, default=0,
+                    help="cut the model to this many layer groups at full "
+                         "width (0 = published depth)")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--clients", type=int, default=4)
     ap.add_argument("--batch-per-client", type=int, default=2)
@@ -82,25 +104,40 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def run(args, devices=None):
+    """Train per `args` (`parse_args`) on `devices` (default: all).
+
+    Returns `(params0, params, losses)`: the single-client initial
+    params, the final client-stacked params and the per-step losses."""
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
+    if args.depth:
+        cfg = steps_lib.depth_config(cfg, args.depth)
     n = args.clients
     key = jax.random.PRNGKey(args.seed)
     k_init, k_data, k_ev = jax.random.split(key, 3)
     k_graph = jax.random.fold_in(key, 3)  # keeps legacy k_* streams intact
 
-    # mesh: use whatever devices exist, (data=n, model=rest) if possible
-    n_dev = len(jax.devices())
-    model_par = max(n_dev // n, 1)
-    mesh = None
-    if n_dev >= n * model_par and n * model_par > 1:
-        mesh = jax.make_mesh((n, model_par), ("data", "model"))
+    mesh = client_mesh(n, devices)
+    shape = ShapeConfig("train", args.seq, n * args.batch_per_client, "train")
+    param_sh, batch_sh, q_sh = steps_lib.make_shardings(mesh, cfg, shape)
+    jit_step = jax.jit(
+        steps_lib.make_train_step(cfg, mesh, lr=args.lr, mix_mode=args.mix,
+                                  psi=args.psi),
+        in_shardings=(param_sh, batch_sh, q_sh),
+        out_shardings=(param_sh, None))
+    unify_fn = jax.jit(steps_lib.make_unify_step(cfg, mesh),
+                       in_shardings=(param_sh, None), out_shardings=param_sh)
 
     params0 = M.init_params(k_init, cfg)
-    params = jax.tree_util.tree_map(
-        lambda p: jnp.broadcast_to(p[None], (n,) + p.shape).copy(), params0
-    )
+    # stacked straight onto the param shardings: no full copy on one device
+    stack_clients = jax.jit(
+        lambda p0: jax.tree_util.tree_map(
+            lambda p: jnp.broadcast_to(p[None], (n,) + p.shape), p0),
+        out_shardings=param_sh)
+    params = stack_clients(params0)
     # protocol-plane context: graph + weights built once, same path the
     # unified simulation driver uses (repro.api)
     proto_cfg = DracoConfig(num_clients=n, topology=args.topology,
@@ -108,35 +145,16 @@ def main(argv=None):
                             lambda_tx=args.lambda_tx, channel=None)
     ctx = make_context(proto_cfg, graph_key=k_graph)
     q = ctx.q
-    data = make_batches(k_data, cfg, n, per_client=8 * args.batch_per_client,
-                        seq=args.seq)
-
-    if mesh is not None:
-        step_fn = steps_lib.make_train_step(cfg, mesh, lr=args.lr,
-                                            mix_mode=args.mix, psi=args.psi)
-        unify_fn = jax.jit(steps_lib.make_unify_step(cfg, mesh))
-    else:
-        # single-device fallback (pure data-path test)
-        def step_fn(params, batch, q_eff):
-            def client_loss(p_i, b_i):
-                return M.lm_loss(p_i, cfg, b_i)
-
-            loss, grads = jax.vmap(jax.value_and_grad(client_loss))(params, batch)
-            delta = jax.tree_util.tree_map(lambda g: -args.lr * g, grads)
-            add = mixing.mix_dense(q_eff, delta)
-            new_params = jax.tree_util.tree_map(
-                lambda p, a: p + a.astype(p.dtype), params, add)
-            return new_params, loss.mean()
-
-        unify_fn = jax.jit(steps_lib.make_unify_step(cfg, None))
-    jit_step = jax.jit(step_fn)
+    data = jax.device_put(make_batches(k_data, cfg, n,
+                                       per_client=8 * args.batch_per_client,
+                                       seq=args.seq), batch_sh)
 
     start = 0
     if args.ckpt_dir:
         latest = ckpt_lib.latest_step(args.ckpt_dir)
         if latest is not None:
             params = ckpt_lib.restore(args.ckpt_dir, params, latest)
-            params = jax.tree_util.tree_map(jnp.asarray, params)
+            params = jax.device_put(params, param_sh)
             start = latest
             print(f"restored step {latest}")
 
@@ -149,7 +167,8 @@ def main(argv=None):
         if args.psi > 0:
             q_eff = mixing.psi_cap_mask(jax.random.fold_in(k_s, 7), q_eff, args.psi)
         batch = select_batch(data, step, args.batch_per_client)
-        params, loss = jit_step(params, batch, q_eff)
+        params, loss = jit_step(params, jax.device_put(batch, batch_sh),
+                                jax.device_put(q_eff, q_sh))
         losses.append(float(loss))
         if args.unify_every and (step + 1) % args.unify_every == 0:
             hub = jnp.asarray((step // args.unify_every) % n, jnp.int32)
@@ -164,7 +183,12 @@ def main(argv=None):
             print(f"saved checkpoint @ {step+1}")
 
     print(f"final loss {np.mean(losses[-10:]):.4f} (first 10: {np.mean(losses[:10]):.4f})")
-    return losses
+    return params0, params, losses
+
+
+def main(argv=None):
+    enable_compile_cache()
+    return run(parse_args(argv))[2]
 
 
 if __name__ == "__main__":

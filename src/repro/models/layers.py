@@ -62,3 +62,18 @@ def cross_entropy(logits, labels, mask=None):
         nll = nll * mask
         return nll.sum() / jnp.maximum(mask.sum(), 1)
     return nll.mean()
+
+
+def top1_accuracy(logits, labels):
+    """Share of rows whose first maximal logit is at the label: `argmax`
+    semantics, written as a max and a min reduction.
+
+    On four TPU v5e chips the variadic (value, index) reduce that
+    `argmax` lowers to gave wrong indices inside the client-sharded
+    sweep scan (accuracy 0.0495 where the one-device grid and an eval
+    outside the scan read 0.0933, for bit-identical params), while the
+    plain reductions of `cross_entropy` agreed."""
+    m = logits.max(-1, keepdims=True)
+    c = logits.shape[-1]
+    first = jnp.min(jnp.where(logits == m, jnp.arange(c), c), axis=-1)
+    return (first == labels).mean()

@@ -30,7 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.layers import apply_rope, cross_entropy, dense_init, init_mlp
+from repro.models.layers import (apply_rope, cross_entropy, dense_init,
+                                  init_mlp, top1_accuracy)
 from repro.models.layers import mlp as swiglu_mlp
 from repro.models.layers import rms_norm
 from repro.tasks.base import Task, register_task
@@ -174,7 +175,7 @@ def _cnn_base(side, num_classes, channels, per_client, alpha, noise) -> Task:
         return cross_entropy(apply(params, x), y)
 
     def accuracy(params, x, y):
-        return (apply(params, x).argmax(-1) == y).mean()
+        return top1_accuracy(apply(params, x), y)
 
     return Task(
         name="small-cnn", init_params=init, loss_fn=loss, eval_fn=accuracy,

@@ -128,6 +128,31 @@ def test_simulate_compiles_once_per_algo_cfg(task):
     assert _run._cache_size() == n0 + 1
 
 
+def test_simulate_traces_matmuls_at_highest(task):
+    """Every dot of the simulation program (local update, drain, eval) is
+    traced at `MATMUL_PRECISION`: f32 on TPU, where the default is bf16."""
+    train, test, params0, loss, acc = task
+    cfg = _cfg()
+    algo = get_algorithm("draco")
+    ctx = make_context(cfg, loss, train, params0=params0)
+    state = algo.init(jax.random.PRNGKey(0), cfg, params0, task=ctx.task)
+    text = _run.lower(algo, ctx, state, test, 4, 2, acc, "accuracy").as_text()
+    dots = [ln for ln in text.splitlines() if "stablehlo.dot_general" in ln]
+    assert dots and all("precision = [HIGHEST, HIGHEST]" in ln for ln in dots)
+
+
+def test_ctx_use_kernel_is_static(task):
+    """The drain's lowering choice rides the context's static aux data:
+    it survives flatten/unflatten and keys a separate compile."""
+    train, _, params0, loss, _ = task
+    ctx = make_context(_cfg(), loss, train, params0=params0)
+    xla = ctx.replace(use_kernel=False)
+    leaves, treedef = jax.tree_util.tree_flatten(xla)
+    assert jax.tree_util.tree_unflatten(treedef, leaves).use_kernel is False
+    assert ctx.use_kernel is None
+    assert treedef != jax.tree_util.tree_structure(ctx)
+
+
 @pytest.mark.slow
 def test_shared_context_reused_across_methods(task):
     """One SimContext drives every method (graph built once)."""

@@ -1,6 +1,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.data.synthetic import (
     classification_task,
@@ -9,6 +10,7 @@ from repro.data.synthetic import (
     lm_token_batches,
     make_mlp,
 )
+from repro.models.layers import top1_accuracy
 
 
 def test_classification_shapes():
@@ -158,3 +160,17 @@ def test_classification_task_anchor_reuse_determinism():
     d = np.linalg.norm(np.asarray(x4) - np.asarray(anchors)[np.asarray(y4)],
                        axis=1)
     assert d.max() < 0.1
+
+
+@pytest.mark.parametrize("rounded", [False, True], ids=["distinct", "ties"])
+def test_top1_accuracy_is_argmax(rounded):
+    """`top1_accuracy` keeps argmax semantics, ties going to the first
+    maximal class."""
+    key = jax.random.PRNGKey(0)
+    logits = jax.random.normal(key, (2, 64, 7))
+    if rounded:
+        logits = jnp.round(logits)  # integers in [-3, 3]: many exact ties
+    labels = jax.random.randint(jax.random.fold_in(key, 1), (2, 64), 0, 7)
+    for y in (labels, logits.argmax(-1), jnp.zeros_like(labels)):
+        assert float(top1_accuracy(logits, y)) == float(
+            (logits.argmax(-1) == y).mean())

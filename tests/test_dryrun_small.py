@@ -33,7 +33,7 @@ from repro.configs.base import get_reduced, ShapeConfig
 from repro.launch import steps as steps_lib, mesh as mesh_lib
 from repro.core.topology import adjacency, row_stochastic
 import repro.models.model as M
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = mesh_lib.make_test_mesh((4, 2))
 assert len(jax.devices()) == 8
 """
 

@@ -1,6 +1,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core.mixing import mix_dense, psi_cap_mask, receive_counts
 from repro.core.topology import adjacency, row_stochastic
@@ -27,6 +28,19 @@ def test_mix_dense_kernel_path():
     ref = mix_dense(q, deltas)
     out = mix_dense(q, deltas, use_kernel=True, interpret=True)
     np.testing.assert_allclose(np.asarray(out["w"]), np.asarray(ref["w"]),
+                               atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("n,d", [(2, 1300), (25, 513)])
+def test_mix_dense_kernel_path_unpadded(n, d):
+    """The kernel's blocks span all N rows (N need not be a multiple of 8)
+    and the last K tile is ragged; no padded copy is made."""
+    key = jax.random.PRNGKey(n)
+    q = jax.nn.softmax(jax.random.normal(key, (n, n)))
+    deltas = {"w": jax.random.normal(jax.random.fold_in(key, 1), (n, d))}
+    out = mix_dense(q, deltas, use_kernel=True, interpret=True)
+    np.testing.assert_allclose(np.asarray(out["w"]),
+                               np.asarray(q).T @ np.asarray(deltas["w"]),
                                atol=1e-5, rtol=1e-5)
 
 

@@ -54,10 +54,23 @@ def test_draco_beats_or_matches_baselines_over_wireless(task):
     assert draco_acc > max(base_accs.values()) - 0.10, (draco_acc, base_accs)
 
 
-def test_trainer_cli_end_to_end(tmp_path):
+def test_trainer_client_mesh_shapes():
+    """Too few devices for one group per client: a (1, 1) mesh on the
+    first device holds every client replica."""
+    from repro.launch.train import client_mesh
+
+    one = client_mesh(4, jax.devices()[:1])
+    assert dict(one.shape) == {"data": 1, "model": 1}
+    assert one.devices.ravel().tolist() == jax.devices()[:1]
+
+
+def test_trainer_cli_end_to_end(tmp_path, monkeypatch):
     """examples-grade driver: reduced arch trains and checkpoints resume."""
+    from repro.launch import train as train_lib
     from repro.launch.train import main as train_main
 
+    # the CLI's persistent compile cache stays out of the test session
+    monkeypatch.setattr(train_lib, "enable_compile_cache", lambda: None)
     ckpt = str(tmp_path / "ck")
     losses = train_main([
         "--arch", "qwen2-1.5b", "--reduced", "--steps", "12", "--clients", "4",
