@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.api.algorithm import Algorithm, get_algorithm
 from repro.api.context import SimContext, make_context
 
@@ -67,6 +68,7 @@ def consensus_distance(params) -> jax.Array:
     return jnp.sqrt(((x - xbar) ** 2).sum() / x.shape[0])
 
 
+@jax.named_scope("draco.eval")
 def _metrics(algo, state, eval_fn, eval_data, metric_name="accuracy"):
     p = algo.eval_params(state)
     out = {"consensus": consensus_distance(p)}
@@ -102,6 +104,7 @@ def _run_body(algo, ctx, state, eval_data, num_steps: int, eval_every: int,
     Un-jitted on purpose: `_run` wraps it for solo `simulate` calls, and
     `repro.api.sweep` nests it under vmap (seed axis) and scan (config
     axis) inside its own jit. Traced at `MATMUL_PRECISION`."""
+    obs.count("repro.trace._run_body")
     with jax.default_matmul_precision(MATMUL_PRECISION):
         return _scan_body(algo, ctx, state, eval_data, num_steps, eval_every,
                           eval_fn, metric_name)
@@ -163,6 +166,10 @@ def simulate(
 ):
     """Run `num_steps` of any registered algorithm in one compiled call.
 
+    Records the `repro.obs` span `repro.simulate`, with `.prepare` (the
+    host entry up to the compiled call), `.run` (the call) and `.sync`
+    (the trace rows' copy to the host, which waits for the device).
+
     Args:
       algo: registry name (e.g. "draco", "sync-push") or an `Algorithm`.
       cfg: `DracoConfig`-style frozen config (static: hashable).
@@ -211,58 +218,62 @@ def simulate(
       (final_state, SimTrace) — the trace holds exactly the sampled
       steps (sized on device; no host-side filtering).
     """
-    from repro.tasks import is_task
+    with obs.span("repro.simulate"):
+        with obs.span("repro.simulate.prepare"):
+            from repro.tasks import is_task
 
-    if isinstance(algo, str):
-        algo = get_algorithm(algo)
-    # params0 feeds state init and the ctx flat-spec (a warm restart with
-    # a prebuilt ctx needs neither); data feeds the ctx (a prebuilt ctx
-    # brings its own shards)
-    task, workload, params0, data, eval_data = resolve_workload(
-        cfg, task, task_key, loss_fn, params0, data, eval_data,
-        need_params=state is None or ctx is None, need_data=ctx is None)
-    if ctx is None:
-        ctx = make_context(cfg, workload, data, params0=params0,
-                           graph_key=graph_key, scenario=scenario,
-                           scenario_key=scenario_key,
-                           scenario_kwargs=scenario_kwargs)
-    elif scenario is not None:
-        raise ValueError(
-            "pass scenario to make_context when prebuilding ctx; a ctx "
-            "already carries its schedule")
-    elif ctx.cfg != cfg:
-        # steps read ctx.cfg, init reads cfg — a silent mismatch would run
-        # the wrong config; rebind with ctx.replace(cfg=...) to share the
-        # traced graph arrays across config variants (e.g. a Psi sweep)
-        raise ValueError(
-            "ctx.cfg differs from cfg; pass ctx.replace(cfg=cfg) to reuse "
-            "a context across config variants")
-    elif workload is not None and ctx.task != workload:
-        # equality, not identity: equal Task instances (e.g. two
-        # with_optimizer() copies) are the same static jit key
-        raise ValueError(
-            "ctx.task differs from the task/loss_fn argument; pass "
-            "ctx.replace(task=...) to rebind the workload")
-    metric_name = "accuracy"
-    if eval_fn is None and is_task(ctx.task) and eval_data is not None:
-        eval_fn = ctx.task.eval_fn
-    if is_task(ctx.task) and eval_fn is ctx.task.eval_fn:
-        metric_name = ctx.task.metric_name
-    if state is None:
-        if key is None:
-            raise ValueError("key is required when no state is given")
-        state = algo.init(key, cfg, params0, task=ctx.task)
-    if eval_fn is not None and eval_data is None:
-        raise ValueError("eval_fn requires eval_data=(ex, ey)")
+            if isinstance(algo, str):
+                algo = get_algorithm(algo)
+            # params0 feeds state init and the ctx flat-spec (a warm restart with
+            # a prebuilt ctx needs neither); data feeds the ctx (a prebuilt ctx
+            # brings its own shards)
+            task, workload, params0, data, eval_data = resolve_workload(
+                cfg, task, task_key, loss_fn, params0, data, eval_data,
+                need_params=state is None or ctx is None, need_data=ctx is None)
+            if ctx is None:
+                ctx = make_context(cfg, workload, data, params0=params0,
+                                   graph_key=graph_key, scenario=scenario,
+                                   scenario_key=scenario_key,
+                                   scenario_kwargs=scenario_kwargs)
+            elif scenario is not None:
+                raise ValueError(
+                    "pass scenario to make_context when prebuilding ctx; a ctx "
+                    "already carries its schedule")
+            elif ctx.cfg != cfg:
+                # steps read ctx.cfg, init reads cfg — a silent mismatch would run
+                # the wrong config; rebind with ctx.replace(cfg=...) to share the
+                # traced graph arrays across config variants (e.g. a Psi sweep)
+                raise ValueError(
+                    "ctx.cfg differs from cfg; pass ctx.replace(cfg=cfg) to reuse "
+                    "a context across config variants")
+            elif workload is not None and ctx.task != workload:
+                # equality, not identity: equal Task instances (e.g. two
+                # with_optimizer() copies) are the same static jit key
+                raise ValueError(
+                    "ctx.task differs from the task/loss_fn argument; pass "
+                    "ctx.replace(task=...) to rebind the workload")
+            metric_name = "accuracy"
+            if eval_fn is None and is_task(ctx.task) and eval_data is not None:
+                eval_fn = ctx.task.eval_fn
+            if is_task(ctx.task) and eval_fn is ctx.task.eval_fn:
+                metric_name = ctx.task.metric_name
+            if state is None:
+                if key is None:
+                    raise ValueError("key is required when no state is given")
+                state = algo.init(key, cfg, params0, task=ctx.task)
+            if eval_fn is not None and eval_data is None:
+                raise ValueError("eval_fn requires eval_data=(ex, ey)")
 
-    state, raw = _run(algo, ctx, state, eval_data, int(num_steps),
-                      int(eval_every), eval_fn, metric_name)
+        with obs.span("repro.simulate.run"):
+            state, raw = _run(algo, ctx, state, eval_data, int(num_steps),
+                              int(eval_every), eval_fn, metric_name)
 
-    if raw is None:
-        return state, SimTrace(np.zeros((0,), np.int32), {})
-    step = np.asarray(raw["step"])
-    metrics = {k: np.asarray(v) for k, v in raw.items() if k != "step"}
-    return state, SimTrace(step, metrics)
+        if raw is None:
+            return state, SimTrace(np.zeros((0,), np.int32), {})
+        with obs.span("repro.simulate.sync"):
+            step = np.asarray(raw["step"])
+            metrics = {k: np.asarray(v) for k, v in raw.items() if k != "step"}
+        return state, SimTrace(step, metrics)
 
 
 def resolve_workload(cfg, task, task_key, loss_fn, params0, data, eval_data,

@@ -434,6 +434,10 @@ def draco_window(state: DracoState, cfg: DracoConfig, q, adj, task, data,
     `use_kernel` picks the drain's lowering (`gossip_ops.gossip_drain`):
     None chooses by backend, False forces XLA's, which a program
     partitioned over several devices needs.
+
+    The phases run under `jax.named_scope`s (`draco.drain`,
+    `draco.local_step`, `draco.tx`, `draco.enqueue`, `draco.unify`),
+    which name their ops in a profile and change no arithmetic.
     """
     n, D = cfg.num_clients, cfg.max_delay_windows
     ov = overrides or Overrides()
@@ -444,50 +448,55 @@ def draco_window(state: DracoState, cfg: DracoConfig, q, adj, task, data,
         spec = flat_lib.spec_of(state.params)
 
     # --- 1. deliveries: fused delay-bucketed drain on the flat plane ------
-    ages, slots, w_stack = drain_weights(state, D)
-    if damping is not None:
-        w_stack = w_stack * damping[ages][:, None, None]
-    arrivals_flat = gossip_ops.gossip_drain(w_stack, state.buffer, slots,
-                                            use_kernel=use_kernel)
-    arrivals = flat_lib.unravel_clients(arrivals_flat, spec)
-    params = jax.tree_util.tree_map(
-        lambda p, a: p + a.astype(p.dtype), state.params, arrivals
-    )
-
-    # --- 2. gradient events ------------------------------------------------
-    lam_g = cfg.lambda_grad if ov.lambda_grad is None else ov.lambda_grad
-    if compute_rate is not None:
-        lam_g = lam_g * compute_rate
-    grad_mask = sample_event_masks(k_grad, lam_g, cfg.window, n)
-    delta, opt_state = local_step(k_gsel, params, grad_mask, cfg, task, data,
-                                  state.opt_state, widx, lr=ov.lr)
-    pending = state.pending + flat_lib.ravel_clients(delta)
-    if cfg.apply_self_update:
+    with jax.named_scope("draco.drain"):
+        ages, slots, w_stack = drain_weights(state, D)
+        if damping is not None:
+            w_stack = w_stack * damping[ages][:, None, None]
+        arrivals_flat = gossip_ops.gossip_drain(w_stack, state.buffer, slots,
+                                                use_kernel=use_kernel)
+        arrivals = flat_lib.unravel_clients(arrivals_flat, spec)
         params = jax.tree_util.tree_map(
-            lambda p, dl: p + dl.astype(p.dtype), params, delta
+            lambda p, a: p + a.astype(p.dtype), state.params, arrivals
         )
 
+    # --- 2. gradient events ------------------------------------------------
+    with jax.named_scope("draco.local_step"):
+        lam_g = cfg.lambda_grad if ov.lambda_grad is None else ov.lambda_grad
+        if compute_rate is not None:
+            lam_g = lam_g * compute_rate
+        grad_mask = sample_event_masks(k_grad, lam_g, cfg.window, n)
+        delta, opt_state = local_step(k_gsel, params, grad_mask, cfg, task, data,
+                                      state.opt_state, widx, lr=ov.lr)
+        pending = state.pending + flat_lib.ravel_clients(delta)
+        if cfg.apply_self_update:
+            params = jax.tree_util.tree_map(
+                lambda p, dl: p + dl.astype(p.dtype), params, delta
+            )
+
     # --- 3. transmission events + channel ----------------------------------
-    tx_mask, w_eff, delay_w, accept_count, total_accept = _tx_and_accept(
-        state, cfg, q, adj, k_tx, k_chan, k_psi, positions=positions,
-        tx_rate=tx_rate, overrides=overrides,
-    )
+    with jax.named_scope("draco.tx"):
+        tx_mask, w_eff, delay_w, accept_count, total_accept = _tx_and_accept(
+            state, cfg, q, adj, k_tx, k_chan, k_psi, positions=positions,
+            tx_rate=tx_rate, overrides=overrides,
+        )
 
     # enqueue: write this window's broadcast (payload + per-link metadata)
     # into ring slot widx % D; the bucketed mixing happens at drain time
-    slot = jnp.mod(widx, D)
-    buffer = jax.lax.dynamic_update_slice(
-        state.buffer, pending[None], (slot, 0, 0)
-    )
-    w_ring = state.w_ring.at[slot].set(w_eff)
-    delay_ring = state.delay_ring.at[slot].set(delay_w)
+    with jax.named_scope("draco.enqueue"):
+        slot = jnp.mod(widx, D)
+        buffer = jax.lax.dynamic_update_slice(
+            state.buffer, pending[None], (slot, 0, 0)
+        )
+        w_ring = state.w_ring.at[slot].set(w_eff)
+        delay_ring = state.delay_ring.at[slot].set(delay_w)
 
-    # senders clear their pending backlog (Lemma A.1 backups are now sent)
-    pending = pending * (~tx_mask).astype(jnp.float32)[:, None]
+        # senders clear their pending backlog (Lemma A.1 backups are now sent)
+        pending = pending * (~tx_mask).astype(jnp.float32)[:, None]
 
     # --- 4. periodic unification -------------------------------------------
     if cfg.unify_period > 0:
-        params, accept_count = _unify(params, accept_count, widx, cfg, n)
+        with jax.named_scope("draco.unify"):
+            params, accept_count = _unify(params, accept_count, widx, cfg, n)
 
     return DracoState(
         params=params,

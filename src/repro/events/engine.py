@@ -146,20 +146,21 @@ def event_step(state: EventState, ctx, *, damping=None,
     k_next, k_gsel, k_chan, _ = keys
 
     # --- 1. continuous-time drain: everything due by t ---------------------
-    slots = jnp.mod(state.tx_count + jnp.arange(D, dtype=jnp.int32), D)
-    due = state.deadline_ring <= t  # (D, N, N)
-    w_live = state.w_ring * due.astype(state.w_ring.dtype)
-    w_stack = w_live[slots]
-    if damping is not None:
-        dtau = (t - state.send_time[slots]) / cfg.window
-        w_stack = w_stack * damping(dtau)[:, None, None]
-    arrivals_flat = gossip_ops.gossip_drain(w_stack, state.buffer, slots,
-                                            use_kernel=ctx.use_kernel)
-    arrivals = flat_lib.unravel_clients(arrivals_flat, spec)
-    params = jax.tree_util.tree_map(
-        lambda p, a: p + a.astype(p.dtype), state.params, arrivals
-    )
-    w_ring = state.w_ring * (~due).astype(state.w_ring.dtype)
+    with jax.named_scope("event.drain"):
+        slots = jnp.mod(state.tx_count + jnp.arange(D, dtype=jnp.int32), D)
+        due = state.deadline_ring <= t  # (D, N, N)
+        w_live = state.w_ring * due.astype(state.w_ring.dtype)
+        w_stack = w_live[slots]
+        if damping is not None:
+            dtau = (t - state.send_time[slots]) / cfg.window
+            w_stack = w_stack * damping(dtau)[:, None, None]
+        arrivals_flat = gossip_ops.gossip_drain(w_stack, state.buffer, slots,
+                                                use_kernel=ctx.use_kernel)
+        arrivals = flat_lib.unravel_clients(arrivals_flat, spec)
+        params = jax.tree_util.tree_map(
+            lambda p, a: p + a.astype(p.dtype), state.params, arrivals
+        )
+        w_ring = state.w_ring * (~due).astype(state.w_ring.dtype)
 
     carry = (params, state.pending, state.opt_state, w_ring,
              state.deadline_ring, state.buffer, state.send_time,
@@ -167,6 +168,7 @@ def event_step(state: EventState, ctx, *, damping=None,
              state.tx_count)
 
     # --- 2. dispatch on the event kind -------------------------------------
+    @jax.named_scope("event.grad")
     def grad_branch(c):
         (params, pending, opt_state, w_ring, dl_ring, buffer, send_time,
          acc, tot, sent, txc) = c
@@ -181,6 +183,7 @@ def event_step(state: EventState, ctx, *, damping=None,
         return (params, pending, opt_state, w_ring, dl_ring, buffer,
                 send_time, acc, tot, sent, txc)
 
+    @jax.named_scope("event.tx")
     def tx_branch(c):
         (params, pending, opt_state, w_ring, dl_ring, buffer, send_time,
          acc, tot, sent, txc) = c
@@ -229,6 +232,7 @@ def event_step(state: EventState, ctx, *, damping=None,
         return (params, pending, opt_state, w_ring, dl_ring, buffer,
                 send_time, acc, tot, sent, txc)
 
+    @jax.named_scope("event.unify")
     def unify_branch(c):
         (params, pending, opt_state, w_ring, dl_ring, buffer, send_time,
          acc, tot, sent, txc) = c

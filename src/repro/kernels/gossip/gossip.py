@@ -48,6 +48,7 @@ def gossip_mix_pallas(q, deltas, *, block_d: int = 512, interpret: bool = False)
     grid = (pl.cdiv(d_total, block_d),)
     return pl.pallas_call(
         _gossip_kernel,
+        name="gossip_mix",
         grid=grid,
         in_specs=[
             pl.BlockSpec((n, n), lambda i: (0, 0)),  # Q resident in VMEM
@@ -85,6 +86,7 @@ def gossip_enqueue_pallas(w_stack, pending, *, block_d: int = 512,
     grid = (k_total // block_d,)
     return pl.pallas_call(
         _enqueue_kernel,
+        name="gossip_enqueue",
         grid=grid,
         in_specs=[
             pl.BlockSpec((j_total, n, n), lambda i: (0, 0, 0)),  # VMEM resident
@@ -126,6 +128,7 @@ def gossip_drain_pallas(w_stack, payloads, *, block_d: int = 512,
     grid = (k_total // block_d,)
     return pl.pallas_call(
         _drain_kernel,
+        name="gossip_drain",
         grid=grid,
         in_specs=[
             pl.BlockSpec((j_total, n, m), lambda i: (0, 0, 0)),  # VMEM resident

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core import mixing
 from repro.launch import mesh as mesh_lib
@@ -234,33 +235,34 @@ def make_train_step(cfg: ModelConfig, mesh, *, lr: float = 1e-3,
     use_kernel = None if mesh.size == 1 else False
 
     def train_step(params, batch, q_eff):
+        obs.count("repro.trace.train_step")
+
         def client_loss(p_i, b_i):
             return M.lm_loss(p_i, cfg, b_i, blocked_attn_threshold=bat,
                              unroll_groups=unroll, vocab_chunk=vocab_chunk)
 
         with use_rules(rules):
-            loss, grads = jax.vmap(
-                jax.value_and_grad(client_loss), spmd_axis_name=spmd_axis
-            )(params, batch)
-            delta = jax.tree_util.tree_map(lambda g: (-lr * g).astype(g.dtype), grads)
+            with jax.named_scope("train.grad"):
+                loss, grads = jax.vmap(
+                    jax.value_and_grad(client_loss), spmd_axis_name=spmd_axis
+                )(params, batch)
+                delta = jax.tree_util.tree_map(lambda g: (-lr * g).astype(g.dtype), grads)
             if mix_mode == "dense":
-                md = mix_dtype or jnp.float32
-                add = mixing.mix_dense(q_eff, delta, compute_dtype=md,
-                                       use_kernel=use_kernel)
+                with jax.named_scope("train.mix"):
+                    add = mixing.mix_dense(q_eff, delta,
+                                           compute_dtype=mix_dtype or jnp.float32,
+                                           use_kernel=use_kernel)
+            elif mix_mode == "ring":
+                with jax.named_scope("train.mix"):
+                    add = mixing.mix_ring_shardmap(mesh, caxes, delta)
+            elif mix_mode == "none":
+                add = delta
+            else:
+                raise ValueError(mix_mode)
+            with jax.named_scope("train.apply"):
                 new_params = jax.tree_util.tree_map(
                     lambda p, a: p + a.astype(p.dtype), params, add
                 )
-            elif mix_mode == "ring":
-                mixed = mixing.mix_ring_shardmap(mesh, caxes, delta)
-                new_params = jax.tree_util.tree_map(
-                    lambda p, m: p + m.astype(p.dtype), params, mixed
-                )
-            elif mix_mode == "none":
-                new_params = jax.tree_util.tree_map(
-                    lambda p, d: p + d.astype(p.dtype), params, delta
-                )
-            else:
-                raise ValueError(mix_mode)
         return new_params, loss.mean()
 
     return train_step
@@ -270,12 +272,14 @@ def make_unify_step(cfg: ModelConfig, mesh):
     """Periodic unification: hub's params broadcast to every client."""
 
     def unify_step(params, hub):
-        return jax.tree_util.tree_map(
-            lambda p: jnp.broadcast_to(
-                jax.lax.dynamic_index_in_dim(p, hub, 0, keepdims=True), p.shape
-            ),
-            params,
-        )
+        obs.count("repro.trace.unify_step")
+        with jax.named_scope("train.unify"):
+            return jax.tree_util.tree_map(
+                lambda p: jnp.broadcast_to(
+                    jax.lax.dynamic_index_in_dim(p, hub, 0, keepdims=True), p.shape
+                ),
+                params,
+            )
 
     return unify_step
 

@@ -3,7 +3,7 @@
 Trains an assigned architecture (usually a reduced variant on CPU; the
 full config on a real mesh) with the production-plane DRACO window step:
 per-client local grads, row-stochastic gossip mixing with per-window
-event/Psi masks, periodic unification, checkpointing and eval.
+event/Psi masks, periodic unification and checkpointing.
 
 Protocol-plane construction (gossip graph, row-stochastic Q, Metropolis
 weights) goes through `repro.api.make_context`, the same context the
@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import checkpoint as ckpt_lib
+from repro import obs
 from repro.api import make_context
 from repro.configs.base import ShapeConfig, get_config, get_reduced
 from repro.core import mixing
@@ -111,79 +112,100 @@ def run(args, devices=None):
     """Train per `args` (`parse_args`) on `devices` (default: all).
 
     Returns `(params0, params, losses)`: the single-client initial
-    params, the final client-stacked params and the per-step losses."""
-    cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
-    if args.depth:
-        cfg = steps_lib.depth_config(cfg, args.depth)
-    n = args.clients
-    key = jax.random.PRNGKey(args.seed)
-    k_init, k_data, k_ev = jax.random.split(key, 3)
-    k_graph = jax.random.fold_in(key, 3)  # keeps legacy k_* streams intact
+    params, the final client-stacked params and the per-step losses.
 
-    mesh = client_mesh(n, devices)
-    shape = ShapeConfig("train", args.seq, n * args.batch_per_client, "train")
-    param_sh, batch_sh, q_sh = steps_lib.make_shardings(mesh, cfg, shape)
-    jit_step = jax.jit(
-        steps_lib.make_train_step(cfg, mesh, lr=args.lr, mix_mode=args.mix,
-                                  psi=args.psi),
-        in_shardings=(param_sh, batch_sh, q_sh),
-        out_shardings=(param_sh, None))
-    unify_fn = jax.jit(steps_lib.make_unify_step(cfg, mesh),
-                       in_shardings=(param_sh, None), out_shardings=param_sh)
+    Records `repro.obs` spans: the root `repro.train.run`, its
+    `repro.train.entry` (everything before the first step) and one
+    `repro.train.step` per step, whose children split the step's host
+    work (`events`, `batch`, `dispatch`) from the wait for the device
+    (`sync`) and the periodic `unify` and `ckpt`."""
+    with obs.span("repro.train.run"):
+        with obs.span("repro.train.entry"):
+            cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
+            if args.depth:
+                cfg = steps_lib.depth_config(cfg, args.depth)
+            n = args.clients
+            key = jax.random.PRNGKey(args.seed)
+            k_init, k_data, k_ev = jax.random.split(key, 3)
+            k_graph = jax.random.fold_in(key, 3)  # keeps legacy k_* streams intact
 
-    params0 = M.init_params(k_init, cfg)
-    # stacked straight onto the param shardings: no full copy on one device
-    stack_clients = jax.jit(
-        lambda p0: jax.tree_util.tree_map(
-            lambda p: jnp.broadcast_to(p[None], (n,) + p.shape), p0),
-        out_shardings=param_sh)
-    params = stack_clients(params0)
-    # protocol-plane context: graph + weights built once, same path the
-    # unified simulation driver uses (repro.api)
-    proto_cfg = DracoConfig(num_clients=n, topology=args.topology,
-                            psi=args.psi, unify_period=args.unify_every,
-                            lambda_tx=args.lambda_tx, channel=None)
-    ctx = make_context(proto_cfg, graph_key=k_graph)
-    q = ctx.q
-    data = jax.device_put(make_batches(k_data, cfg, n,
-                                       per_client=8 * args.batch_per_client,
-                                       seq=args.seq), batch_sh)
+            mesh = client_mesh(n, devices)
+            shape = ShapeConfig("train", args.seq, n * args.batch_per_client, "train")
+            param_sh, batch_sh, q_sh = steps_lib.make_shardings(mesh, cfg, shape)
+            jit_step = jax.jit(
+                steps_lib.make_train_step(cfg, mesh, lr=args.lr, mix_mode=args.mix,
+                                          psi=args.psi),
+                in_shardings=(param_sh, batch_sh, q_sh),
+                out_shardings=(param_sh, None))
+            unify_fn = jax.jit(steps_lib.make_unify_step(cfg, mesh),
+                               in_shardings=(param_sh, None), out_shardings=param_sh)
 
-    start = 0
-    if args.ckpt_dir:
-        latest = ckpt_lib.latest_step(args.ckpt_dir)
-        if latest is not None:
-            params = ckpt_lib.restore(args.ckpt_dir, params, latest)
-            params = jax.device_put(params, param_sh)
-            start = latest
-            print(f"restored step {latest}")
+            params0 = M.init_params(k_init, cfg)
 
-    losses = []
-    t0 = time.time()
-    for step in range(start, args.steps):
-        k_s = jax.random.fold_in(k_ev, step)
-        tx = sample_event_masks(k_s, args.lambda_tx, 1.0, n)
-        q_eff = q * tx[:, None].astype(q.dtype)
-        if args.psi > 0:
-            q_eff = mixing.psi_cap_mask(jax.random.fold_in(k_s, 7), q_eff, args.psi)
-        batch = select_batch(data, step, args.batch_per_client)
-        params, loss = jit_step(params, jax.device_put(batch, batch_sh),
-                                jax.device_put(q_eff, q_sh))
-        losses.append(float(loss))
-        if args.unify_every and (step + 1) % args.unify_every == 0:
-            hub = jnp.asarray((step // args.unify_every) % n, jnp.int32)
-            params = unify_fn(params, hub)
-        if (step + 1) % args.log_every == 0:
-            dt = time.time() - t0
-            print(f"step {step+1:5d} loss {np.mean(losses[-args.log_every:]):.4f} "
-                  f"({dt/args.log_every:.2f}s/step)")
-            t0 = time.time()
-        if args.ckpt_dir and args.ckpt_every and (step + 1) % args.ckpt_every == 0:
-            ckpt_lib.save(args.ckpt_dir, step + 1, jax.device_get(params))
-            print(f"saved checkpoint @ {step+1}")
+            def stack_clients(p0):
+                obs.count("repro.trace.stack_clients")
+                return jax.tree_util.tree_map(
+                    lambda p: jnp.broadcast_to(p[None], (n,) + p.shape), p0)
 
-    print(f"final loss {np.mean(losses[-10:]):.4f} (first 10: {np.mean(losses[:10]):.4f})")
-    return params0, params, losses
+            # stacked straight onto the param shardings: no full copy on one device
+            stack_fn = jax.jit(stack_clients, out_shardings=param_sh)
+            params = stack_fn(params0)
+            # protocol-plane context: graph + weights built once, same path the
+            # unified simulation driver uses (repro.api)
+            proto_cfg = DracoConfig(num_clients=n, topology=args.topology,
+                                    psi=args.psi, unify_period=args.unify_every,
+                                    lambda_tx=args.lambda_tx, channel=None)
+            ctx = make_context(proto_cfg, graph_key=k_graph)
+            q = ctx.q
+            data = jax.device_put(make_batches(k_data, cfg, n,
+                                               per_client=8 * args.batch_per_client,
+                                               seq=args.seq), batch_sh)
+
+            start = 0
+            if args.ckpt_dir:
+                latest = ckpt_lib.latest_step(args.ckpt_dir)
+                if latest is not None:
+                    params = ckpt_lib.restore(args.ckpt_dir, params, latest)
+                    params = jax.device_put(params, param_sh)
+                    start = latest
+                    print(f"restored step {latest}")
+
+        losses = []
+        t0 = time.time()
+        for step in range(start, args.steps):
+            with obs.span("repro.train.step"):
+                with obs.span("repro.train.events"):
+                    k_s = jax.random.fold_in(k_ev, step)
+                    tx = sample_event_masks(k_s, args.lambda_tx, 1.0, n)
+                    q_eff = q * tx[:, None].astype(q.dtype)
+                    if args.psi > 0:
+                        q_eff = mixing.psi_cap_mask(jax.random.fold_in(k_s, 7), q_eff,
+                                                    args.psi)
+                    q_eff = jax.device_put(q_eff, q_sh)
+                with obs.span("repro.train.batch"):
+                    batch = jax.device_put(
+                        select_batch(data, step, args.batch_per_client), batch_sh)
+                with obs.span("repro.train.dispatch"):
+                    params, loss = jit_step(params, batch, q_eff)
+                with obs.span("repro.train.sync"):
+                    losses.append(float(loss))
+                if args.unify_every and (step + 1) % args.unify_every == 0:
+                    with obs.span("repro.train.unify"):
+                        hub = jnp.asarray((step // args.unify_every) % n, jnp.int32)
+                        params = unify_fn(params, hub)
+                if (step + 1) % args.log_every == 0:
+                    dt = time.time() - t0
+                    print(f"step {step+1:5d} loss {np.mean(losses[-args.log_every:]):.4f} "
+                          f"({dt/args.log_every:.2f}s/step)")
+                    t0 = time.time()
+                if args.ckpt_dir and args.ckpt_every and (step + 1) % args.ckpt_every == 0:
+                    with obs.span("repro.train.ckpt"):
+                        ckpt_lib.save(args.ckpt_dir, step + 1, jax.device_get(params))
+                    print(f"saved checkpoint @ {step+1}")
+
+        print(f"final loss {np.mean(losses[-10:]):.4f} "
+              f"(first 10: {np.mean(losses[:10]):.4f})")
+        return params0, params, losses
 
 
 def main(argv=None):

@@ -11,15 +11,20 @@ only one process may load the TPU library, so every worker collects the
 same tests and only the one running this file loads it.
 """
 import os
+import re
+import sys
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-from repro.kernels.gossip.gossip import gossip_drain_pallas, gossip_mix_pallas
-from repro.kernels.gossip.ops import gossip_drain_sharded
-from repro.kernels.ssd.ssd import ssd_chunk_pallas
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from bench import trace as bench_trace  # noqa: E402
+from repro.kernels.gossip.gossip import (  # noqa: E402
+    gossip_drain_pallas, gossip_enqueue_pallas, gossip_mix_pallas)
+from repro.kernels.gossip.ops import gossip_drain_sharded  # noqa: E402
+from repro.kernels.ssd.ssd import ssd_chunk_pallas  # noqa: E402
 
 # paper scale: EMNIST-like MLP (Dflat 146,447 padded to the 512 block),
 # N=25 clients padded to 32, ring depth 8 -> J=7 stored broadcasts
@@ -49,6 +54,23 @@ def _sds(shape, sharding, dtype=jnp.float32):
 
 def _compiled_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
+
+
+_DEF = re.compile(r"%([\w.\-]+) = (\S+) ")
+
+
+def _as_traced(text, kernel):
+    """The compiled instruction of the Pallas kernel named `kernel` as a
+    device trace names the op: its operands printed with their shapes
+    (`compiled.as_text()` prints their names alone)."""
+    shapes = dict(_DEF.findall(text))
+    line = next(x for x in text.splitlines()
+                if re.search(rf"%{kernel}(\.\d+)? = .*tpu_custom_call", x))
+    head, call = line.strip().removeprefix("ROOT ").split("custom-call(", 1)
+    operands, rest = call.split(")", 1)
+    typed = ", ".join(f"{shapes[o.strip()[1:]]} {o.strip()}"
+                      for o in operands.split(","))
+    return f"{head}custom-call({typed}){rest}"
 
 
 @pytest.mark.parametrize("ring_dtype", [jnp.float32, jnp.bfloat16])
@@ -113,3 +135,24 @@ def test_drain_sharded_compiles_on_four_chips(topo):
     # the psum_scatter of the partials; at this size the TPU compiler
     # lowers it as an all-reduce plus a local slice
     assert "reduce-scatter" in text or "all-reduce" in text
+
+
+def test_kernels_carry_their_names_and_the_readers_find_them(one_chip):
+    """Each gossip kernel compiles under its own name, and the drain's and
+    the mix's instructions still match the patterns by which
+    `drain_roofline.sim` and `mix_roofline.train` find them in a trace."""
+    drain = _compiled_text(
+        lambda w, p: gossip_drain_pallas(w, p),
+        _sds((J, N_PAD, N_PAD), one_chip), _sds((J, N_PAD, K_PAD), one_chip))
+    mix = _compiled_text(
+        lambda q, d: gossip_mix_pallas(q, d),
+        _sds((2, 2), one_chip), _sds((2, 4096), one_chip))
+    enqueue = _compiled_text(
+        lambda w, p: gossip_enqueue_pallas(w, p),
+        _sds((J, N_PAD, N_PAD), one_chip), _sds((N_PAD, 4096), one_chip))
+    drain_op, mix_op = _as_traced(drain, "gossip_drain"), _as_traced(mix, "gossip_mix")
+    assert _as_traced(enqueue, "gossip_enqueue")
+    assert bench_trace.pallas_call(3).search(drain_op), drain_op[:300]
+    assert not bench_trace.pallas_call(2).search(drain_op)
+    assert bench_trace.pallas_call(2).search(mix_op), mix_op[:300]
+    assert not bench_trace.pallas_call(3).search(mix_op)
