@@ -1,22 +1,38 @@
-"""Pallas TPU kernel: row-stochastic gossip aggregation.
+"""Pallas TPU kernels: row-stochastic gossip aggregation.
 
-Computes ``out = Q^T @ deltas`` for a small (N, N) mixing matrix Q and a
-huge (N, D) stacked-update matrix (D = flattened parameter count /
-tensor-parallel shard — hundreds of MB in production).
+``gossip_mix`` computes ``out = Q^T @ deltas`` for a small (N, N) mixing
+matrix Q and a huge (N, K) stacked-update plane (K = flattened parameter
+count: 3.3e8 per client for the trainer's Qwen2 stage, 1.3 GB in f32).
+The drain and enqueue kernels below serve the simulators' delay rings.
 
-TPU-native blocking rationale:
-  - D is tiled into ``block_d`` lanes (multiple of 128 to match the MXU
-    lane width); each grid step streams one (N, block_d) tile of deltas
-    HBM->VMEM, multiplies by the resident (N, N) Q tile on the MXU and
-    writes one (N, block_d) output tile. Every delta byte moves exactly
-    once — the kernel is purely memory-bound, matching its roofline role.
-  - Each block spans all N rows (the client axis, 16..64), so N needs
-    no 8-sublane padding; accumulation is f32 regardless of input dtype
-    (bf16 deltas are common).
-  - Every dot runs at ``precision=HIGHEST``: the protocol mixes in f32,
-    and the MXU's default single bf16 pass would round the f32 weights
-    and payloads to 8 mantissa bits. The kernels are memory-bound, so
-    the extra passes cost no time.
+Blocking of the mix:
+  - The plane stays 2-D and unpadded: at K = 3.3e8 and N = 2 it lies in
+    HBM as ``f32[2, K]{1,0:T(2,128)}`` (rows tiled by the next power of
+    two up to 8), which a (N, block_d) block reads as it is. A 3-D view
+    (N, K/512, 512) is a bitcast to another tiling, not the row-major
+    layout a Pallas operand takes, and its compile did not finish.
+    Each block spans all N rows, and the last K block may be ragged:
+    output columns depend only on their own input columns, and its
+    out-of-bounds lanes are never written.
+  - ``block_d`` comes from a VMEM budget (`mix_block_d`): one input
+    buffer holds about ``MIX_BLOCK_BYTES`` of the plane as VMEM tiles it
+    (rows rounded up to the tiling), so each grid step streams that much
+    in and out. A grid step costs a fixed fraction of a microsecond, so
+    small blocks make the kernel grid-bound: the former (2, 512) tiles
+    took 638,615 steps, 0.18 s, for a pass whose bytes take 6.4 ms at
+    819 GB/s. Input and output, double-buffered, stay inside the 16 MiB
+    of VMEM a v5e kernel may use by default.
+  - The contraction body follows the static N. Up to ``MIX_VPU_MAX_N``
+    clients, each output row is ``sum_j q[j, i] * d[j]`` on the VPU, Q's
+    scalars read from SMEM, accumulated in f32 in sender order: exact
+    f32 arithmetic. Above it the MXU multiplies at ``precision=HIGHEST``,
+    where the VPU's N^2 scalar products per lane would set the time.
+    Q stays the kernel's first operand, f32 (N, N), in both.
+
+Every dot runs at ``precision=HIGHEST``: the protocol mixes in f32, and
+the MXU's default single bf16 pass would round the f32 weights and
+payloads to 8 mantissa bits. Accumulation is f32 whatever the payload
+dtype (bf16 deltas are common).
 """
 from __future__ import annotations
 
@@ -24,11 +40,52 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _HIGHEST = jax.lax.Precision.HIGHEST
+_LANES = 128
+
+# VMEM bytes of one (N, block_d) input buffer of the mix. On a v5e the
+# kernel streams 600 GB/s from 1 MiB to 4 MiB; at 4 MiB the MXU body
+# passes the 16 MiB scoped VMEM (scripts/mix_blocks.py, PERF.md).
+MIX_BLOCK_BYTES = 2 << 20
+# Largest N mixed on the VPU, the MXU above it. On a v5e at the budget
+# block the VPU body takes 8.7 ms against the MXU's 22.9 ms at N = 2 and
+# 11.5 ms at N = 4; at N = 8 they tie, at 16 the VPU's takes 11.7 ms
+# against 8.7 ms (scripts/mix_blocks.py, PERF.md).
+MIX_VPU_MAX_N = 4
 
 
-def _gossip_kernel(q_ref, d_ref, o_ref):
+def _tile_rows(n: int) -> int:
+    """Rows an (n, block) block occupies in memory: n rounded up to its
+    tiling (1, 2 or 4 rows up to four, then a multiple of 8)."""
+    if n <= 4:
+        return 1 << (n - 1).bit_length()
+    return -(-n // 8) * 8
+
+
+def mix_block_d(n: int, k: int, dtype) -> int:
+    """Lanes per grid step of the mix: a multiple of 128 whose (n, lanes)
+    block fills ``MIX_BLOCK_BYTES`` of VMEM as f32, or all K lanes if
+    fewer. Narrower payloads get the f32 size too: the body's f32
+    working values take VMEM in proportion to the block."""
+    per_lane = _tile_rows(n) * max(jnp.dtype(dtype).itemsize, 4)
+    block = max(MIX_BLOCK_BYTES // per_lane // _LANES, 1) * _LANES
+    return min(block, k)
+
+
+def _mix_vpu_kernel(q_ref, d_ref, o_ref):
+    """out[i] = sum_j q[j, i] * d[j]: Q's scalars from SMEM, f32 sums in
+    sender order."""
+    n = d_ref.shape[0]
+    for i in range(n):
+        acc = q_ref[0, i] * d_ref[0:1, :].astype(jnp.float32)
+        for j in range(1, n):
+            acc = acc + q_ref[j, i] * d_ref[j:j + 1, :].astype(jnp.float32)
+        o_ref[i:i + 1, :] = acc.astype(o_ref.dtype)
+
+
+def _mix_mxu_kernel(q_ref, d_ref, o_ref):
     q = q_ref[...].astype(jnp.float32)  # (N, N) resident
     d = d_ref[...].astype(jnp.float32)  # (N, block_d)
     o_ref[...] = jnp.dot(
@@ -36,28 +93,32 @@ def _gossip_kernel(q_ref, d_ref, o_ref):
     ).astype(o_ref.dtype)
 
 
-def gossip_mix_pallas(q, deltas, *, block_d: int = 512, interpret: bool = False):
-    """q (N, N) f32; deltas (N, K), unpadded.
-
-    Each block spans all N rows (a block dim equal to the array dim needs
-    no 8-sublane multiple), so no padded copy of the (N, K) plane is made.
-    A ragged last K tile is fine: output columns depend only on their own
-    input columns, and its out-of-bounds lanes are never written."""
+def _mix_call(kernel, q, deltas, block_d, interpret):
     n, d_total = deltas.shape
     assert q.shape == (n, n)
-    grid = (pl.cdiv(d_total, block_d),)
+    if kernel is _mix_vpu_kernel:
+        q_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
+    else:
+        q_spec = pl.BlockSpec((n, n), lambda i: (0, 0))  # resident in VMEM
     return pl.pallas_call(
-        _gossip_kernel,
+        kernel,
         name="gossip_mix",
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n, n), lambda i: (0, 0)),  # Q resident in VMEM
-            pl.BlockSpec((n, block_d), lambda i: (0, i)),
-        ],
+        grid=(pl.cdiv(d_total, block_d),),
+        in_specs=[q_spec, pl.BlockSpec((n, block_d), lambda i: (0, i))],
         out_specs=pl.BlockSpec((n, block_d), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((n, d_total), deltas.dtype),
         interpret=interpret,
     )(q, deltas)
+
+
+def gossip_mix_pallas(q, deltas, *, interpret: bool = False):
+    """q (N, N) f32; deltas (N, K), unpadded -> Q^T @ deltas (N, K), in
+    `mix_block_d`'s blocks, on the VPU or the MXU by N (module
+    docstring)."""
+    n, k = deltas.shape
+    kernel = _mix_vpu_kernel if n <= MIX_VPU_MAX_N else _mix_mxu_kernel
+    return _mix_call(kernel, q, deltas, mix_block_d(n, k, deltas.dtype),
+                     interpret)
 
 
 def _enqueue_kernel(w_ref, p_ref, o_ref):

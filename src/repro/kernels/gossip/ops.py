@@ -48,14 +48,16 @@ def _pad_to(x, mult, axis):
     return jnp.pad(x, widths)
 
 
-@functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
-def gossip_mix(q, deltas, *, block_d: int = 512, interpret=None):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def gossip_mix(q, deltas, *, interpret=None):
     """out = Q^T @ deltas; q (N, N) and deltas (N, K) flat updates ->
     (N, K). No padding: at model width the (N, K) plane is most of the
-    device's memory, and a padded copy would double it."""
+    device's memory, and a padded copy would double it. The kernel's
+    block comes from a VMEM budget and its body (VPU or MXU) from N
+    (`gossip.mix_block_d`, `gossip.MIX_VPU_MAX_N`)."""
     if interpret is None:
         interpret = default_interpret()
-    return gossip_mix_pallas(q.astype(jnp.float32), deltas, block_d=block_d,
+    return gossip_mix_pallas(q.astype(jnp.float32), deltas,
                              interpret=interpret)
 
 

@@ -7,10 +7,18 @@ import pytest
 hypothesis = pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
-from repro.kernels.gossip.ops import gossip_mix
-from repro.kernels.gossip.ref import gossip_mix_ref
+from repro.kernels.gossip import gossip  # noqa: E402
+from repro.kernels.gossip.ops import gossip_mix  # noqa: E402
+from repro.kernels.gossip.ref import gossip_mix_ref  # noqa: E402
 
-SHAPES = [(4, 64), (16, 512), (25, 513), (32, 1000), (7, 129), (64, 2048)]
+# the VPU body up to MIX_VPU_MAX_N (4) clients, the MXU above; K below
+# one block (a block of all K lanes), and K past one budget block with a
+# ragged last block (262,144 lanes at N = 2, 131,072 at 4, 65,536 at 8,
+# 32,768 at 16, 16,384 at 25, 8,192 at 64), so that several grid steps run
+SHAPES = [(4, 64), (16, 512), (25, 513), (32, 1000), (7, 129), (64, 2048),
+          (2, 5000), (3, 1000), (2, 262_144 + 1000), (4, 131_072 + 77),
+          (8, 65_536 + 129), (16, 32_768 + 513), (25, 16_384 + 77),
+          (64, 2 * 8_192 + 300)]
 DTYPES = [jnp.float32, jnp.bfloat16]
 
 
@@ -29,6 +37,22 @@ def test_kernel_matches_oracle(shape, dtype):
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
         atol=tol, rtol=tol)
+
+
+def test_mix_blocks_the_train_plane_within_vmem():
+    """The trainer's plane (2 clients x 326,970,880 f32) takes at most
+    5,000 grid steps, not (2, 512) tiles' 638,615, and every block's two
+    input and two output buffers fit the 16 MiB a v5e kernel may use."""
+    k = 326_970_880
+    block = gossip.mix_block_d(2, k, jnp.float32)
+    assert block % 128 == 0
+    assert -(-k // block) <= 5_000
+    for n, dtype in [(2, jnp.float32), (2, jnp.bfloat16), (3, jnp.float32),
+                     (8, jnp.bfloat16), (25, jnp.float32), (64, jnp.float32)]:
+        block = gossip.mix_block_d(n, k, dtype)
+        rows = gossip._tile_rows(n)
+        assert rows >= n and block % 128 == 0
+        assert 4 * rows * block * max(jnp.dtype(dtype).itemsize, 4) <= 16 << 20
 
 
 @pytest.mark.slow

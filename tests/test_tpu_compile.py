@@ -92,10 +92,13 @@ def test_drain_compiles_under_vmap(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_mix_compiles_at_train_width(one_chip):
+@pytest.mark.parametrize("clients", [2, 16])
+def test_mix_compiles_at_train_width(one_chip, clients):
     """`gossip_mix` on the trainer's flat plane: qwen2-1.5b at its
     published width cut to 2 layers, 2 clients (the one-chip smoke run),
-    unpadded."""
+    unpadded: the kernel reads the plane where it lies, with no copy of
+    it, and `mix_roofline.train`'s pattern still finds the kernel. The
+    same bytes over 16 clients take the MXU body."""
     from repro.configs.base import get_config
     from repro.launch.steps import depth_config, param_specs_abstract
 
@@ -103,10 +106,16 @@ def test_mix_compiles_at_train_width(one_chip):
     dflat = sum(x.size for x in
                 jax.tree_util.tree_leaves(param_specs_abstract(cfg)))
     assert dflat > 3e8
+    k = 2 * dflat // clients
     text = _compiled_text(
         lambda q, d: gossip_mix_pallas(q, d),
-        _sds((2, 2), one_chip), _sds((2, dflat), one_chip))
+        _sds((clients, clients), one_chip), _sds((clients, k), one_chip))
     assert "tpu_custom_call" in text
+    plane_copy = re.compile(rf"= f32\[{clients},{k}\]\S* copy(-start)?\(")
+    assert not any(plane_copy.search(x) for x in text.splitlines())
+    mix_op = _as_traced(text, "gossip_mix")
+    assert bench_trace.pallas_call(2).search(mix_op), mix_op[:300]
+    assert not bench_trace.pallas_call(3).search(mix_op)
 
 
 def test_ssd_compiles(one_chip):
