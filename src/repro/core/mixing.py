@@ -1,17 +1,20 @@
 """Gossip aggregation: ``x_j += sum_i q[i,j] * delta_i``.
 
-Three lowering strategies for the same row-stochastic semantics:
+Three pieces of the same row-stochastic semantics:
 
-  - ``mix_dense``   : einsum against the full (N, N) Q. With the client
-    axis sharded over ("pod","data") this lowers to all-gather +
-    local matmul — the paper-faithful baseline (arbitrary digraphs).
-  - ``mix_psi_topk``: applies the paper's Psi cap by keeping only the
+  - ``mix_dense``   : Q^T against the full (N, N) Q, one client-stacked
+    leaf at a time in the leaf's own shape and dtype: the Pallas
+    ``gossip_mix`` kernel on one chip (a bf16 leaf read where it lies,
+    f32 sums), an einsum elsewhere. With the client axis sharded over
+    ("pod","data") the einsum lowers to all-gather + local matmul — the
+    paper-faithful baseline (arbitrary digraphs).
+  - ``psi_cap_mask``: applies the paper's Psi cap by keeping only the
     top-Psi incoming weights per receiver before mixing. On the mesh this
     bounds collective bytes per window — the paper's communication-budget
     knob becomes an ICI-bandwidth knob.
-  - ``mix_ring``    : shard_map + lax.ppermute for cycle/ring topologies —
-    gossip edges map 1:1 onto ICI torus links (beyond-paper optimization;
-    no all-gather, 2 neighbor permutes).
+  - ``mix_ring_shardmap``: shard_map + lax.ppermute for cycle/ring
+    topologies — gossip edges map 1:1 onto ICI torus links (beyond-paper
+    optimization; no all-gather, 2 neighbor permutes).
 
 All operate on pytrees whose leaves have a leading client axis N.
 """
@@ -51,28 +54,37 @@ def psi_cap_mask(key, q, psi: int):
 
 def mix_dense(q_eff, deltas, *, use_kernel=None, interpret=None,
               compute_dtype=jnp.float32):
-    """x_add = Q^T @ deltas on the flat plane. q_eff (N,N) masked/weighted.
+    """x_add = Q^T @ deltas, leaf by leaf. q_eff (N,N) masked/weighted;
+    deltas a pytree of client-stacked (N, ...) leaves.
 
-    The per-client pytree is raveled to one contiguous (N, Dflat) matrix
-    (`repro.core.flat`), mixed with a single GEMM — the Pallas gossip
-    kernel on TPU (`use_kernel=None` auto-selects by backend), a plain
-    einsum elsewhere — and unraveled back, instead of one einsum per leaf.
+    Each leaf is mixed in its own shape and comes back in its own dtype:
+    by the Pallas gossip kernel on TPU (`use_kernel=None` auto-selects by
+    backend), which reads the leaf where it lies and sums in f32, or by a
+    HIGHEST-precision einsum elsewhere. No (N, Dflat) plane is built: at
+    the trainer's width, widening the bf16 update into an f32 plane and
+    slicing it back moved five times the bytes of the mix itself.
 
-    compute_dtype: accumulation dtype of the mixing matmul. f32 is the
-    paper-faithful default; bf16 halves the all-gather bytes on the mesh
-    (beyond-paper knob, see EXPERIMENTS.md §Perf)."""
-    from repro.core import flat as flat_lib
-
+    compute_dtype: accumulation dtype of the einsum lowering (the kernel
+    always sums in f32). f32 is the paper-faithful default; bf16 halves
+    the all-gather bytes on the mesh (beyond-paper knob, see
+    EXPERIMENTS.md §Perf). A leaf that compute_dtype cannot hold exactly
+    is rounded to it first, on either lowering."""
     if use_kernel is None:
         use_kernel = gossip_ops.default_use_kernel()
-    spec = flat_lib.spec_of(deltas)
-    flat = flat_lib.ravel_clients(deltas, dtype=compute_dtype)
-    if use_kernel:
-        out = gossip_ops.gossip_mix(q_eff, flat, interpret=interpret)
-    else:
-        out = jnp.einsum("nm,nk->mk", q_eff.astype(compute_dtype), flat,
-                         precision=jax.lax.Precision.HIGHEST)
-    return flat_lib.unravel_clients(out, spec)
+
+    def mix(x):
+        if not use_kernel:
+            out = jnp.einsum("nm,n...->m...", q_eff.astype(compute_dtype),
+                             x.astype(compute_dtype),
+                             precision=jax.lax.Precision.HIGHEST)
+        elif jnp.promote_types(x.dtype, compute_dtype) != compute_dtype:
+            out = gossip_ops.gossip_mix(q_eff, x.astype(compute_dtype),
+                                        interpret=interpret)
+        else:
+            out = gossip_ops.gossip_mix(q_eff, x, interpret=interpret)
+        return out.astype(x.dtype)
+
+    return jax.tree_util.tree_map(mix, deltas)
 
 
 def apply_mix(params, q_eff, deltas, **kw):

@@ -50,19 +50,25 @@ def _pad_to(x, mult, axis):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gossip_mix(q, deltas, *, interpret=None):
-    """out = Q^T @ deltas; q (N, N) and deltas (N, K) flat updates ->
-    (N, K). No padding: at model width the (N, K) plane is most of the
-    device's memory, and a padded copy would double it. The kernel's
-    block comes from a VMEM budget and its body (VPU or MXU) from N
-    (`gossip.mix_block_d`, `gossip.MIX_VPU_MAX_N`)."""
+    """out = Q^T @ deltas, summed in f32; q (N, N) and deltas (N, K), or
+    any one client-stacked leaf (N, ...), such as a parameter leaf of the
+    trainer's update -> the same shape and dtype. The leaf is read where it
+    lies: no padding and no relayout (an (N,) leaf is mixed as (N, 1)),
+    since at model width a copy would move as many bytes as the mix. The
+    kernel's block comes from a VMEM budget and its body (VPU or MXU)
+    from N (`gossip.mix_block`, `gossip.MIX_VPU_MAX_N`)."""
     if interpret is None:
         interpret = default_interpret()
-    return gossip_mix_pallas(q.astype(jnp.float32), deltas,
-                             interpret=interpret)
+    q = q.astype(jnp.float32)
+    if deltas.ndim == 1:
+        return gossip_mix_pallas(q, deltas[:, None],
+                                 interpret=interpret)[:, 0]
+    return gossip_mix_pallas(q, deltas, interpret=interpret)
 
 
 def gossip_mix_reference(q, deltas):
-    """Pure-jnp oracle: q (N, N), deltas (N, K) -> Q^T @ deltas."""
+    """Pure-jnp oracle: q (N, N), deltas (N, K) or any client-stacked
+    leaf (N, ...) -> Q^T @ deltas."""
     return gossip_mix_ref(q, deltas)
 
 

@@ -6,13 +6,14 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def gossip_mix_ref(q, deltas):
-    """out[m, :] = sum_n q[n, m] * deltas[n, :].
+    """out[m, ...] = sum_n q[n, m] * deltas[n, ...].
 
-    q: (N, N) row-stochastic (sender, receiver), deltas: (N, K).
-    Accumulation in f32, output in deltas.dtype.
+    q: (N, N) row-stochastic (sender, receiver), deltas: (N, K) or any
+    client-stacked leaf (N, ...). Accumulation in f32, output in
+    deltas.dtype.
     """
     out = jnp.einsum(
-        "nm,nd->md", q.astype(jnp.float32), deltas.astype(jnp.float32),
+        "nm,n...->m...", q.astype(jnp.float32), deltas.astype(jnp.float32),
         precision=_HIGHEST)
     return out.astype(deltas.dtype)
 

@@ -59,18 +59,24 @@ def _compiled_text(fn, *args):
 _DEF = re.compile(r"%([\w.\-]+) = (\S+) ")
 
 
-def _as_traced(text, kernel):
-    """The compiled instruction of the Pallas kernel named `kernel` as a
+def _traced_calls(text, kernel):
+    """Every compiled instruction of the Pallas kernel named `kernel` as a
     device trace names the op: its operands printed with their shapes
     (`compiled.as_text()` prints their names alone)."""
     shapes = dict(_DEF.findall(text))
-    line = next(x for x in text.splitlines()
-                if re.search(rf"%{kernel}(\.\d+)? = .*tpu_custom_call", x))
-    head, call = line.strip().removeprefix("ROOT ").split("custom-call(", 1)
-    operands, rest = call.split(")", 1)
-    typed = ", ".join(f"{shapes[o.strip()[1:]]} {o.strip()}"
-                      for o in operands.split(","))
-    return f"{head}custom-call({typed}){rest}"
+    for line in text.splitlines():
+        if not re.search(rf"%{kernel}(\.\d+)? = .*tpu_custom_call", line):
+            continue
+        head, call = line.strip().removeprefix("ROOT ").split("custom-call(", 1)
+        operands, rest = call.split(")", 1)
+        typed = ", ".join(f"{shapes[o.strip()[1:]]} {o.strip()}"
+                          for o in operands.split(","))
+        yield f"{head}custom-call({typed}){rest}"
+
+
+def _as_traced(text, kernel):
+    """The first compiled instruction of the kernel, as `_traced_calls`."""
+    return next(_traced_calls(text, kernel))
 
 
 @pytest.mark.parametrize("ring_dtype", [jnp.float32, jnp.bfloat16])
@@ -94,28 +100,58 @@ def test_drain_compiles_under_vmap(one_chip):
 
 @pytest.mark.parametrize("clients", [2, 16])
 def test_mix_compiles_at_train_width(one_chip, clients):
-    """`gossip_mix` on the trainer's flat plane: qwen2-1.5b at its
-    published width cut to 2 layers, 2 clients (the one-chip smoke run),
-    unpadded: the kernel reads the plane where it lies, with no copy of
-    it, and `mix_roofline.train`'s pattern still finds the kernel. The
-    same bytes over 16 clients take the MXU body."""
+    """`mix_dense` over the trainer's own update: qwen2-1.5b at its
+    published width cut to 2 layers, its 14 bf16 leaves stacked over 2
+    clients (the one-chip cell, the VPU body) or 16 (the MXU body). Each
+    leaf is mixed where it lies, in one `gossip_mix` call: no f32
+    (clients, Dflat) plane, no concatenate, no convert and no copy, and
+    `mix_roofline.train`'s pattern still finds every call by its f32
+    (N, N) first operand."""
     from repro.configs.base import get_config
-    from repro.launch.steps import depth_config, param_specs_abstract
+    from repro.core import mixing
+    from repro.launch.steps import (depth_config, param_specs_abstract,
+                                    stack_clients_abstract)
 
     cfg = depth_config(get_config("qwen2-1.5b"), 2)
-    dflat = sum(x.size for x in
-                jax.tree_util.tree_leaves(param_specs_abstract(cfg)))
+    params = param_specs_abstract(cfg)
+    dflat = sum(x.size for x in jax.tree_util.tree_leaves(params))
     assert dflat > 3e8
-    k = 2 * dflat // clients
+    deltas = jax.tree_util.tree_map(
+        lambda x: _sds(x.shape, one_chip, x.dtype),
+        stack_clients_abstract(params, clients))
+    leaves = jax.tree_util.tree_leaves(deltas)
+    assert {x.dtype for x in leaves} == {jnp.dtype(jnp.bfloat16)}
+    text = _compiled_text(
+        lambda q, d: mixing.mix_dense(q, d, use_kernel=True, interpret=False),
+        _sds((clients, clients), one_chip), deltas)
+    assert f"f32[{clients},{dflat}]" not in text
+    for op in ("concatenate", "convert", "copy", "slice"):
+        assert not re.search(rf"= \S+ {op}(-start)?\(", text), op
+    calls = list(_traced_calls(text, "gossip_mix"))
+    assert len(calls) == len(leaves)
+    for mix_op in calls:
+        assert mix_op.startswith("%gossip_mix"), mix_op[:300]
+        assert bench_trace.pallas_call(2).search(mix_op), mix_op[:300]
+        assert not bench_trace.pallas_call(3).search(mix_op)
+    shapes = {x.shape for x in leaves}
+    assert {tuple(map(int, m.group(1).split(","))) for m in re.finditer(
+        r"= bf16\[([\d,]+)\]\S* custom-call", text)} == shapes
+
+
+@pytest.mark.parametrize("shape", [(5, 3, 40, 130), (2, 7, 1), (5, 4_096, 1),
+                                   (25, 9, 3_000)])
+def test_mix_compiles_odd_leaves(one_chip, shape):
+    """bf16 leaves whose lanes are not a multiple of 128, or whose rows
+    and lanes both end in a ragged block, compile for the chip on both
+    bodies: the MXU's dot per row (N = 5 and 25; 816 rows a block for the
+    one-lane leaf) and the VPU's (N = 2), with Q still the first operand
+    by which `mix_roofline.train` finds the call."""
+    n = shape[0]
     text = _compiled_text(
         lambda q, d: gossip_mix_pallas(q, d),
-        _sds((clients, clients), one_chip), _sds((clients, k), one_chip))
-    assert "tpu_custom_call" in text
-    plane_copy = re.compile(rf"= f32\[{clients},{k}\]\S* copy(-start)?\(")
-    assert not any(plane_copy.search(x) for x in text.splitlines())
+        _sds((n, n), one_chip), _sds(shape, one_chip, jnp.bfloat16))
     mix_op = _as_traced(text, "gossip_mix")
     assert bench_trace.pallas_call(2).search(mix_op), mix_op[:300]
-    assert not bench_trace.pallas_call(3).search(mix_op)
 
 
 def test_ssd_compiles(one_chip):
