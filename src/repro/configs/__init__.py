@@ -1,7 +1,6 @@
 from repro.configs.base import (
     ARCH_ALIASES,
     ARCH_IDS,
-    SHAPES,
     ModelConfig,
     ShapeConfig,
     all_configs,
@@ -12,7 +11,6 @@ from repro.configs.base import (
 __all__ = [
     "ARCH_ALIASES",
     "ARCH_IDS",
-    "SHAPES",
     "ModelConfig",
     "ShapeConfig",
     "all_configs",

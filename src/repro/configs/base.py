@@ -141,7 +141,7 @@ class ModelConfig:
 
 
 # ---------------------------------------------------------------------------
-# Input shapes (assigned)
+# Input shape of a train step
 # ---------------------------------------------------------------------------
 
 
@@ -150,15 +150,8 @@ class ShapeConfig:
     name: str
     seq_len: int
     global_batch: int
-    mode: str  # "train" | "prefill" | "decode"
+    mode: str  # "train"
 
-
-SHAPES = {
-    "train_4k": ShapeConfig("train_4k", 4_096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32_768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524_288, 1, "decode"),
-}
 
 ARCH_IDS = (
     "mamba2_2p7b",

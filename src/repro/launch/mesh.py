@@ -1,11 +1,9 @@
-"""Production mesh construction — every mesh of the repo is built here.
+"""Mesh construction — every mesh of the repo is built here.
 
-Single pod: (16, 16) = 256 chips, axes ("data", "model") — 16 DRACO
-clients x 16-way tensor parallel. Multi-pod: (2, 16, 16) = 512 chips,
-axes ("pod", "data", "model") — 32 clients spanning 2 pods; the gossip
-graph's client axis is the flattened ("pod", "data") product, so gossip
-edges cross the inter-pod links (DCN/optical) exactly where the paper's
-protocol tolerates delay.
+Axes ("data", "model"): the DRACO client axis on "data" (one client per
+device group), tensor parallel on "model". `launch.train.client_mesh`
+sizes it from the visible devices; the sweep and the tests use the
+builders below.
 
 Defined as functions (never module-level constants) so importing this
 module does not touch jax device state.
@@ -25,12 +23,6 @@ def make_mesh(shape, axes, *, devices=None):
     (Auto) sharding. `devices` defaults to all visible devices."""
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
                          devices=devices)
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):

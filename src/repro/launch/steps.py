@@ -1,18 +1,15 @@
-"""Production-plane step functions + abstract input specs.
+"""The trainer's step functions and the abstract specs of their inputs.
 
 ``make_train_step``: one DRACO superposition window on the mesh — every
-client (= model-shard group on the ("pod","data") axes) runs a local
-grad step, forms Delta, and the row-stochastic gossip mix is applied as a
-collective over the client axis. Event masks / channel masks arrive as
-the per-window effective Q (q_eff) input, so the compiled step is purely
-data-dependent (no host control flow).
+client (= model-shard group on the client axis) runs a local grad step,
+forms Delta, and the row-stochastic gossip mix is applied over the
+client axis. Event masks / channel masks arrive as the per-window
+effective Q (q_eff) input, so the compiled step is purely data-dependent
+(no host control flow). ``make_unify_step``: periodic unification.
 
-``make_serve_step`` / ``make_prefill_step``: decode one token against a
-KV/SSM cache; prefill a full prompt. Serving uses the *unified* model
-(single param copy), per DESIGN.md §4.
-
-``input_specs``: ShapeDtypeStruct stand-ins for every model input of an
-(arch x shape) pair — weak-type-correct, shardable, no allocation.
+``train_batch_specs``, ``param_specs_abstract``,
+``stack_clients_abstract`` and ``make_shardings``: ShapeDtypeStruct
+stand-ins and shardings for the step's inputs — no allocation.
 """
 from __future__ import annotations
 
@@ -27,7 +24,7 @@ from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core import mixing
 from repro.launch import mesh as mesh_lib
 from repro.models import model as M
-from repro.sharding.axes import default_rules, train_rules, use_rules
+from repro.sharding.axes import train_rules, use_rules
 from repro.sharding.specs import tree_param_specs
 
 
@@ -54,37 +51,6 @@ def train_batch_specs(cfg: ModelConfig, shape: ShapeConfig, n_clients: int):
     return specs
 
 
-def serve_input_specs(cfg: ModelConfig, shape: ShapeConfig):
-    """Decode-step inputs: current token + cache state (+ cross KV)."""
-    B, S = shape.global_batch, shape.seq_len
-    serve_cfg = serve_config(cfg, shape)
-    state = jax.eval_shape(lambda: M.init_decode_state(serve_cfg, B, S))
-    if cfg.embeds_in:
-        tok = jax.ShapeDtypeStruct((B, 1, cfg.d_model), jnp.bfloat16)
-    else:
-        tok = jax.ShapeDtypeStruct((B,), jnp.int32)
-    cross = None
-    if cfg.family == "vlm":
-        pe = jax.ShapeDtypeStruct((B, cfg.num_patch_tokens, cfg.d_model), jnp.bfloat16)
-        params_s = jax.eval_shape(lambda k: M.init_params(k, serve_cfg), jax.random.PRNGKey(0))
-        cross = jax.eval_shape(
-            lambda p, e: M.init_cross_kv(p, serve_cfg, e), params_s, pe
-        )
-    return tok, state, cross
-
-
-def prefill_batch_specs(cfg: ModelConfig, shape: ShapeConfig):
-    B, S = shape.global_batch, shape.seq_len
-    specs: Dict[str, Any] = {}
-    if cfg.embeds_in:
-        specs["embeds"] = jax.ShapeDtypeStruct((B, S, cfg.d_model), jnp.bfloat16)
-    else:
-        specs["tokens"] = jax.ShapeDtypeStruct((B, S), jnp.int32)
-    if cfg.family == "vlm":
-        specs["cross_embeds"] = jax.ShapeDtypeStruct((B, cfg.num_patch_tokens, cfg.d_model), jnp.bfloat16)
-    return specs
-
-
 def param_specs_abstract(cfg: ModelConfig):
     return jax.eval_shape(lambda k: M.init_params(k, cfg), jax.random.PRNGKey(0))
 
@@ -93,17 +59,6 @@ def stack_clients_abstract(params_abs, n_clients: int):
     return jax.tree_util.tree_map(
         lambda l: jax.ShapeDtypeStruct((n_clients,) + tuple(l.shape), l.dtype), params_abs
     )
-
-
-def serve_config(cfg: ModelConfig, shape: ShapeConfig) -> ModelConfig:
-    """Serving variant: attention archs get a sliding window at 500k ctx
-    (sub-quadratic requirement); ssm/hybrid decode natively."""
-    if shape.name == "long_500k" and cfg.family in ("dense", "moe", "vlm", "audio"):
-        return cfg.with_(sliding_window=8192)
-    if shape.name == "long_500k" and cfg.family == "hybrid":
-        # hybrid: SSM layers are O(1); the shared attn block uses a window
-        return cfg.with_(sliding_window=8192)
-    return cfg
 
 
 # ---------------------------------------------------------------------------
@@ -131,78 +86,13 @@ def make_shardings(mesh, cfg: ModelConfig, shape: ShapeConfig):
     return param_sh, bspecs, q_sh
 
 
-def serve_shardings(mesh, cfg: ModelConfig, shape: ShapeConfig,
-                    cache_shard: str = "kv_heads"):
-    """Shardings for (params single-copy, token, decode state, cross_kv).
-
-    cache_shard: 'kv_heads' shards the KV-head axis over "model"
-    (baseline; falls back to replicated when kv_heads % 16 != 0 — the
-    GQA pathology measured in §Roofline). 'head_dim' shards the head_dim
-    axis instead (always divisible; attention contracts over it with a
-    psum — Megatron-style reduction split). 'seq' shards the cache
-    length axis over "model"."""
-    caxes = mesh_lib.client_axes(mesh)
-    cax = caxes if len(caxes) > 1 else caxes[0]
-    B = shape.global_batch
-    batch_shardable = B % mesh_lib.num_clients(mesh) == 0
-    batch_ax = cax if batch_shardable else None
-    # long-context batch=1: shard the cache sequence axis over 'data'
-    seq_ax = None if batch_shardable else "data"
-
-    scfg = serve_config(cfg, shape)
-    params_abs = param_specs_abstract(scfg)
-    pspecs = tree_param_specs(params_abs, prefix=(), mesh=mesh)
-    param_sh = jax.tree_util.tree_map(lambda s: NamedSharding(mesh, s), pspecs)
-
-    tok, state, cross = serve_input_specs(cfg, shape)
-    if cfg.embeds_in:
-        tok_sh = NamedSharding(mesh, P(batch_ax, None, None))
-    else:
-        tok_sh = NamedSharding(mesh, P(batch_ax))
-
-    from repro.sharding.specs import filter_divisible
-
-    def cache_spec(path, leaf):
-        name = "/".join(str(getattr(p, "key", getattr(p, "name", p))) for p in path)
-        nd = len(leaf.shape)
-        if nd == 0:
-            spec = P()
-        elif "ssm" in name and nd == 4:  # conv state (n_groups, B, W-1, ch)
-            spec = P(None, batch_ax, None, "model")
-        elif "ssm" in name and nd == 5:  # h (n_groups, B, H, N, P)
-            spec = P(None, batch_ax, "model", None, None)
-        elif nd == 5:  # KV cache (n_groups, B, C, Hkv, hd)
-            if cache_shard == "head_dim":
-                spec = P(None, batch_ax, seq_ax, None, "model")
-            elif cache_shard == "seq":
-                spec = P(None, batch_ax, "model", None, None)
-            else:
-                spec = P(None, batch_ax, seq_ax, "model", None)
-        else:
-            spec = P(*([None] * nd))
-        return filter_divisible(spec, leaf.shape, mesh)
-
-    state_sh = jax.tree_util.tree_map_with_path(
-        lambda p, l: NamedSharding(mesh, cache_spec(p, l)), state,
-    )
-    cross_sh = None
-    if cross is not None:
-        cross_sh = jax.tree_util.tree_map(
-            lambda l: NamedSharding(
-                mesh, filter_divisible(P(None, batch_ax, None, "model", None), l.shape, mesh)
-            ),
-            cross,
-        )
-    return param_sh, tok_sh, state_sh, cross_sh, scfg
-
-
 # ---------------------------------------------------------------------------
 # Step builders
 # ---------------------------------------------------------------------------
 
 
 def depth_config(cfg: ModelConfig, k: int) -> ModelConfig:
-    """Same width, depth reduced to k layer-groups (cost-correction compiles)."""
+    """Same width, depth reduced to k layer-groups."""
     from repro.models.model import block_pattern
 
     _, n_groups = block_pattern(cfg)
@@ -211,24 +101,16 @@ def depth_config(cfg: ModelConfig, k: int) -> ModelConfig:
 
 
 def make_train_step(cfg: ModelConfig, mesh, *, lr: float = 1e-3,
-                    mix_mode: str = "dense", psi: int = 0,
-                    unroll: bool = False, cost_variant: bool = False,
-                    mix_dtype=None, blocked_threshold: int = 8192,
-                    vocab_chunk: int = 0, seq_parallel: bool = False):
+                    mix_mode: str = "dense"):
     """One DRACO window: local grad -> Delta -> gossip mix -> apply.
 
-    mix_mode: 'dense' (paper-faithful row-stochastic einsum over the
-    client axis), 'ring' (collective_permute cycle lowering), or 'none'
-    (no gossip — isolates local compute for roofline attribution).
-    mix_dtype: gossip accumulation dtype (f32 faithful; bf16 halves
-    collective bytes). blocked_threshold: seq length at which training
-    attention switches to the blocked online-softmax path (memory knob).
-    cost_variant disables inner-loop attention so XLA cost_analysis sees
-    every flop (see dryrun depth-correction).
+    lr: the local step's learning rate (Delta = -lr * grad).
+    mix_mode: 'dense' (paper-faithful row-stochastic mix over the client
+    axis, `mixing.mix_dense`), 'ring' (collective_permute cycle
+    lowering), or 'none' (no gossip: each client keeps its own Delta).
     """
     caxes = mesh_lib.client_axes(mesh)
-    rules = train_rules(mesh, seq_parallel=seq_parallel)
-    bat = 10**9 if cost_variant else blocked_threshold
+    rules = train_rules(mesh)
     spmd_axis = caxes if len(caxes) > 1 else caxes[0]
     # the partitioner splits the einsum mix; it cannot split the Pallas
     # kernel, which runs only where the mesh is one device
@@ -238,8 +120,7 @@ def make_train_step(cfg: ModelConfig, mesh, *, lr: float = 1e-3,
         obs.count("repro.trace.train_step")
 
         def client_loss(p_i, b_i):
-            return M.lm_loss(p_i, cfg, b_i, blocked_attn_threshold=bat,
-                             unroll_groups=unroll, vocab_chunk=vocab_chunk)
+            return M.lm_loss(p_i, cfg, b_i)
 
         with use_rules(rules):
             with jax.named_scope("train.grad"):
@@ -249,9 +130,7 @@ def make_train_step(cfg: ModelConfig, mesh, *, lr: float = 1e-3,
                 delta = jax.tree_util.tree_map(lambda g: (-lr * g).astype(g.dtype), grads)
             if mix_mode == "dense":
                 with jax.named_scope("train.mix"):
-                    add = mixing.mix_dense(q_eff, delta,
-                                           compute_dtype=mix_dtype or jnp.float32,
-                                           use_kernel=use_kernel)
+                    add = mixing.mix_dense(q_eff, delta, use_kernel=use_kernel)
             elif mix_mode == "ring":
                 with jax.named_scope("train.mix"):
                     add = mixing.mix_ring_shardmap(mesh, caxes, delta)
@@ -282,33 +161,3 @@ def make_unify_step(cfg: ModelConfig, mesh):
             )
 
     return unify_step
-
-
-def make_prefill_step(cfg: ModelConfig, shape: ShapeConfig, mesh, *,
-                      unroll: bool = False, cost_variant: bool = False):
-    scfg = serve_config(cfg, shape)
-    rules = default_rules(mesh)
-    bat = 10**9 if cost_variant else 8192
-
-    def prefill_step(params, batch):
-        with use_rules(rules):
-            logits, _ = M.apply_model(params, scfg, batch,
-                                      blocked_attn_threshold=bat,
-                                      unroll_groups=unroll)
-        return logits[:, -1, :]
-
-    return prefill_step
-
-
-def make_serve_step(cfg: ModelConfig, shape: ShapeConfig, mesh, *,
-                    unroll: bool = False):
-    scfg = serve_config(cfg, shape)
-    rules = default_rules(mesh)
-
-    def serve_step(params, tok, state, cross_kv=None):
-        with use_rules(rules):
-            logits, state = M.decode_step(params, scfg, tok, state, cross_kv,
-                                          unroll_groups=unroll)
-        return logits, state
-
-    return serve_step

@@ -133,8 +133,7 @@ def run(args, devices=None):
             shape = ShapeConfig("train", args.seq, n * args.batch_per_client, "train")
             param_sh, batch_sh, q_sh = steps_lib.make_shardings(mesh, cfg, shape)
             jit_step = jax.jit(
-                steps_lib.make_train_step(cfg, mesh, lr=args.lr, mix_mode=args.mix,
-                                          psi=args.psi),
+                steps_lib.make_train_step(cfg, mesh, lr=args.lr, mix_mode=args.mix),
                 in_shardings=(param_sh, batch_sh, q_sh),
                 out_shardings=(param_sh, None))
             unify_fn = jax.jit(steps_lib.make_unify_step(cfg, mesh),

@@ -15,7 +15,7 @@ the block probabilities in the backward pass:
 
 Residual memory: q,k,v + out + (B,H,S) stats — O(S), never O(S^2).
 This is exactly what a Pallas/TPU flash kernel does; expressed here in
-lax.scan form so the XLA dry-run measures its memory behaviour.
+lax.scan form so XLA's memory analysis of the compiled step sees it.
 
 Layout: q (B,H,S,hd), k/v (B,H,T,hd) (kv heads already repeated or
 grouped by the caller). Causal + optional sliding window.

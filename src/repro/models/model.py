@@ -52,7 +52,9 @@ def block_pattern(cfg: ModelConfig) -> Tuple[Tuple[str, ...], int]:
     raise ValueError(cfg.family)
 
 
-_CACHED_KINDS = ("attn", "ssm", "shared", "cross")
+# sequence length from which self-attention takes the blocked
+# online-softmax path (`attention.flash_self_attention`)
+BLOCKED_ATTN_THRESHOLD = 8192
 
 
 # ---------------------------------------------------------------------------
@@ -169,21 +171,16 @@ def _apply_block(kind, bp, h, cfg, *, mode, shared, cross_embeds, sliding_window
     raise ValueError(kind)
 
 
-def apply_model(params, cfg: ModelConfig, batch, *, blocked_attn_threshold: int = 8192,
-                unroll_groups: bool = False, return_hidden: bool = False):
-    """Full-sequence forward. Returns (logits (B,S,V), aux scalar).
-
-    ``unroll_groups`` replaces the scan-over-layer-groups with a python
-    loop (used by the dry-run cost-correction compiles, where XLA's
-    cost_analysis counts while-loop bodies once)."""
-    pattern, n_groups = block_pattern(cfg)
+def apply_model(params, cfg: ModelConfig, batch):
+    """Full-sequence forward. Returns (logits (B,S,V), aux scalar)."""
+    pattern, _ = block_pattern(cfg)
     h = _embed_inputs(params, cfg, batch)
     B, S, _ = h.shape
     h = constrain(h, "batch", "seq", None)
     cross_embeds = batch.get("cross_embeds") if cfg.family == "vlm" else None
     if cross_embeds is not None:
         cross_embeds = cross_embeds.astype(h.dtype)
-    use_blocked = S >= blocked_attn_threshold and cfg.family != "ssm"
+    use_blocked = S >= BLOCKED_ATTN_THRESHOLD and cfg.family != "ssm"
     sw = cfg.sliding_window
     shared = params.get("shared")
 
@@ -204,19 +201,9 @@ def apply_model(params, cfg: ModelConfig, batch, *, blocked_attn_threshold: int 
 
     if cfg.remat:
         group_fn = jax.checkpoint(group_fn)
-    if unroll_groups:
-        aux_total = jnp.zeros((), jnp.float32)
-        for g in range(n_groups):
-            gp = jax.tree_util.tree_map(lambda x: x[g], params["groups"])
-            h, aux = group_fn(h, gp)
-            aux_total = aux_total + aux
-    else:
-        h, auxs = jax.lax.scan(group_fn, h, params["groups"])
-        aux_total = auxs.sum()
-    if return_hidden:
-        h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-        return h, aux_total
-    return _logits(params, cfg, h), aux_total
+    h, auxs = jax.lax.scan(group_fn, h, params["groups"])
+    aux = auxs.sum()
+    return _logits(params, cfg, h), aux
 
 
 def _labels_and_mask(batch):
@@ -231,45 +218,13 @@ def _labels_and_mask(batch):
     return labels, mask
 
 
-def lm_loss(params, cfg, batch, *, vocab_chunk: int = 0, **kw):
-    """Next-token CE loss (labels = inputs shifted, or batch['labels']).
-
-    vocab_chunk > 0 enables the chunked-CE path: the (B,S,V) logits are
-    never materialized — the sequence is scanned in chunks with per-chunk
-    remat, so the backward recomputes each chunk's logits (the logits +
-    f32 CE intermediates are the dominant training activation for
-    large-vocab models; measured in §Perf)."""
+def lm_loss(params, cfg, batch):
+    """Next-token CE loss (labels = inputs shifted, or batch['labels'])."""
     from repro.models.layers import cross_entropy
 
     labels, mask = _labels_and_mask(batch)
-    if vocab_chunk <= 0:
-        logits, aux = apply_model(params, cfg, batch, **kw)
-        return cross_entropy(logits, labels, mask) + aux
-
-    h, aux = apply_model(params, cfg, batch, return_hidden=True, **kw)
-    w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    B, S, _ = h.shape
-    C = vocab_chunk
-    n_chunks = S // C
-    assert S % C == 0, (S, C)
-    hc = h.reshape(B, n_chunks, C, -1).swapaxes(0, 1)
-    lc = labels.reshape(B, n_chunks, C).swapaxes(0, 1)
-    mc = mask.reshape(B, n_chunks, C).swapaxes(0, 1)
-
-    @jax.checkpoint
-    def chunk_fn(carry, inp):
-        h_c, l_c, m_c = inp
-        logits = h_c @ w  # (B,C,V) — lives only inside this chunk
-        logits = logits.astype(jnp.float32)
-        logz = jax.scipy.special.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, l_c[..., None], axis=-1)[..., 0]
-        nll = (logz - gold) * m_c
-        return (carry[0] + nll.sum(), carry[1] + m_c.sum()), None
-
-    (tot, cnt), _ = jax.lax.scan(
-        chunk_fn, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-        (hc, lc, mc))
-    return tot / jnp.maximum(cnt, 1.0) + aux
+    logits, aux = apply_model(params, cfg, batch)
+    return cross_entropy(logits, labels, mask) + aux
 
 
 # ---------------------------------------------------------------------------
@@ -325,7 +280,7 @@ def init_cross_kv(params, cfg, patch_embeds):
 
 
 def decode_step(params, cfg: ModelConfig, token_or_embed, state: DecodeState,
-                cross_kv=None, *, unroll_groups: bool = False):
+                cross_kv=None):
     """One decode step. token (B,) int32 or embed (B,1,d). Returns
     (logits (B,V), new DecodeState)."""
     pattern, n_groups = block_pattern(cfg)
@@ -384,14 +339,6 @@ def decode_step(params, cfg: ModelConfig, token_or_embed, state: DecodeState,
     else:
         cross_dummy = cross_kv
     xs = (params["groups"], state.caches, cross_dummy)
-    if unroll_groups:
-        new_list = []
-        for g in range(n_groups):
-            xg = jax.tree_util.tree_map(lambda x: x[g], xs)
-            h, gc_new = group_fn(h, xg)
-            new_list.append(gc_new)
-        new_caches = jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *new_list)
-    else:
-        h, new_caches = jax.lax.scan(group_fn, h, xs)
+    h, new_caches = jax.lax.scan(group_fn, h, xs)
     logits = _logits(params, cfg, h)[:, 0, :]
     return logits, DecodeState(caches=new_caches, pos=pos + 1)

@@ -24,8 +24,8 @@ def build_model(cfg_or_name) -> Model:
     return Model(
         cfg=cfg,
         init=lambda key: M.init_params(key, cfg),
-        apply=lambda params, batch, **kw: M.apply_model(params, cfg, batch, **kw),
-        loss=lambda params, batch, **kw: M.lm_loss(params, cfg, batch, **kw),
+        apply=lambda params, batch: M.apply_model(params, cfg, batch),
+        loss=lambda params, batch: M.lm_loss(params, cfg, batch),
         init_decode_state=lambda batch, seq_len: M.init_decode_state(cfg, batch, seq_len),
         decode_step=lambda params, tok, state, cross_kv=None: M.decode_step(
             params, cfg, tok, state, cross_kv

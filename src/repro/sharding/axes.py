@@ -40,9 +40,9 @@ def default_rules(mesh: Mesh) -> AxisRules:
         mesh=mesh,
         rules={
             "clients": client_axes if multi_pod else "data",
-            "batch": client_axes if multi_pod else "data",  # serving batch
+            "batch": client_axes if multi_pod else "data",
             "seq": None,
-            "cache_seq": None,  # overridden to 'data' for long-context decode
+            "cache_seq": None,
             "heads": "model",
             "kv_heads": "model",
             "ff": "model",
@@ -55,21 +55,14 @@ def default_rules(mesh: Mesh) -> AxisRules:
     )
 
 
-def train_rules(mesh: Mesh, seq_parallel: bool = False) -> AxisRules:
+def train_rules(mesh: Mesh) -> AxisRules:
     """Rules for code running *inside* the per-client vmap: the client axis
     is handled by vmap(spmd_axis_name=...), so logical batch stays
-    unsharded and only model-parallel axes constrain.
-
-    seq_parallel=True maps the residual-stream 'seq' axis onto "model"
-    (Megatron-style sequence parallelism): the per-layer saved carries of
-    the remat'd layer scan shard 16x instead of replicating within the
-    tensor-parallel group."""
+    unsharded and only model-parallel axes constrain."""
     base = default_rules(mesh)
     rules = dict(base.rules)
     rules["batch"] = None
     rules["clients"] = None
-    if seq_parallel:
-        rules["seq"] = "model"
     return AxisRules(mesh=mesh, rules=rules)
 
 

@@ -1,7 +1,7 @@
 """Mesh integration tests in subprocesses (XLA device count must be set
 before jax initializes, so these run out-of-process on an 8-device CPU
 mesh with reduced configs). Validates the full launch path: shardings,
-DRACO window step, gossip lowering (dense + ring), serve step."""
+DRACO window step, gossip lowering (dense + ring), unification."""
 import os
 import subprocess
 import sys
@@ -80,27 +80,6 @@ np.testing.assert_allclose(np.asarray(dense["w"]), np.asarray(ring["w"]),
 print("RING_OK")
 """)
     assert "RING_OK" in out
-
-
-def test_serve_step_executes_on_mesh():
-    out = _run(PRELUDE + """
-cfg = get_reduced("mamba2-2.7b")
-shape = ShapeConfig("d", 64, 8, "decode")
-step = steps_lib.make_serve_step(cfg, shape, mesh)
-param_sh, tok_sh, state_sh, cross_sh, scfg = steps_lib.serve_shardings(mesh, cfg, shape)
-key = jax.random.PRNGKey(0)
-params = jax.device_put(M.init_params(key, scfg), param_sh)
-state = jax.device_put(M.init_decode_state(scfg, 8, 64), state_sh)
-tok = jax.device_put(jnp.zeros((8,), jnp.int32), tok_sh)
-jitted = jax.jit(step, in_shardings=(param_sh, tok_sh, state_sh),
-                 out_shardings=(None, state_sh))
-logits, state = jitted(params, tok, state)
-assert np.isfinite(np.asarray(logits)).all()
-logits2, state = jitted(params, tok, state)
-assert int(state.pos) == 2
-print("SERVE_OK")
-""")
-    assert "SERVE_OK" in out
 
 
 def test_unify_step_on_mesh():

@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.configs.base import ARCH_IDS, SHAPES, get_config, get_reduced
+from repro.configs.base import ARCH_IDS, get_config, get_reduced
 from repro.models.registry import build_model
 
 # tier-2: heavy reduced-arch smoke battery (~95s) (ROADMAP tier-1 runs -m "not slow")
@@ -101,10 +101,3 @@ def test_full_config_exact_assignment(arch):
         assert (cfg.num_experts, cfg.experts_per_token) == (64, 8)
     if arch in ("qwen2p5_32b", "qwen2_1p5b"):
         assert cfg.qkv_bias
-
-
-def test_shapes_table():
-    assert SHAPES["train_4k"].seq_len == 4096 and SHAPES["train_4k"].global_batch == 256
-    assert SHAPES["prefill_32k"].seq_len == 32768 and SHAPES["prefill_32k"].global_batch == 32
-    assert SHAPES["decode_32k"].seq_len == 32768 and SHAPES["decode_32k"].global_batch == 128
-    assert SHAPES["long_500k"].seq_len == 524288 and SHAPES["long_500k"].global_batch == 1

@@ -85,11 +85,3 @@ def test_trainer_cli_end_to_end(tmp_path, monkeypatch):
         "--ckpt-dir", ckpt, "--log-every", "2",
     ])
     assert len(losses2) == 2  # only steps 12->14 ran
-
-
-def test_serve_cli_end_to_end():
-    from repro.launch.serve import main as serve_main
-
-    toks = serve_main(["--arch", "musicgen-large", "--reduced", "--batch", "2",
-                       "--prompt-len", "4", "--new-tokens", "4"])
-    assert toks.shape == (2, 4)
